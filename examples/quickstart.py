@@ -85,7 +85,7 @@ def main():
     print(f"serve: [{loop.scheduler_kind}] {len(results)} requests / "
           f"{served} tokens, mean occupancy {occ['mean']:.2f}")
     # CLI equivalent:
-    #   python -m repro.launch.serve --arrival poisson --requests 8 \
+    #   python -m repro.launch.serve --smoke --arrival poisson --requests 8 \
     #       --batch 4 --ragged --scheduler continuous --metrics-json m.json
 
     # Chunked prefill + copy-on-write prefix sharing: prefill runs in
@@ -103,7 +103,7 @@ def main():
     hit = shared.scheduler.cache.cache_hit_ratio
     print(f"serve: prefix sharing cache-hit ratio {hit:.2f}")
     # CLI equivalent:
-    #   python -m repro.launch.serve --arrival poisson --requests 8 \
+    #   python -m repro.launch.serve --smoke --arrival poisson --requests 8 \
     #       --prefix-len 16 --prefix-group 2 --block-len 8 --prefix-cache \
     #       --chunk-tokens 16 --metrics-json m.json
 
@@ -238,7 +238,7 @@ def main():
     #       --chrome-trace t.chrome.json
     #   python -m repro.obs.cli summary --trace t.jsonl
     #   python -m repro.obs.cli compare BENCH_base.json BENCH_new.json
-    #   python -m repro.launch.serve --ragged --metrics-json m.json
+    #   python -m repro.launch.serve --smoke --ragged --metrics-json m.json
 
 
 if __name__ == "__main__":

@@ -21,6 +21,7 @@ from typing import List, Optional
 
 from ..core import hardware
 from ..core.async_pipeline import Strategy, parse_strategy
+from ..core.compile_cache import use_persistent_cache
 from ..tuning.registry import Registry
 from . import lineage, runner, scenario
 from .results import BenchReport
@@ -78,8 +79,7 @@ def _emit(stream):
 
 def _options(args, stream) -> runner.RunOptions:
     return runner.RunOptions(
-        warmup=args.warmup, repeats=args.repeats,
-        interpret=not args.compiled, check=not args.no_check,
+        warmup=args.warmup, repeats=args.repeats, check=not args.no_check,
         use_tuned=not args.no_tuned, chip=getattr(args, "chip", None),
         registry=Registry(args.registry) if args.registry else None,
         emit=_emit(stream))
@@ -264,9 +264,6 @@ def main(argv=None) -> int:
                        help="skip the ref-oracle correctness check")
         p.add_argument("--no-tuned", action="store_true",
                        help="ignore the tuning registry; seed defaults only")
-        p.add_argument("--compiled", action="store_true",
-                       help="compile for the real backend instead of the "
-                            "CPU Pallas interpreter (use on TPU)")
         p.add_argument("--registry", default=None,
                        help="tuning registry JSON to resolve configs from")
         p.add_argument("--json", default=None, metavar="PATH",
@@ -319,6 +316,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO if args.verbose
                         else logging.WARNING)
+    use_persistent_cache()
     return args.fn(args)
 
 

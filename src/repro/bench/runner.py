@@ -51,15 +51,27 @@ class RunOptions:
     """Measurement policy for a batch of scenario runs."""
     warmup: int = 1
     repeats: int = 5
-    interpret: bool = True              # Pallas interpreter vs compiled
+    interpret: Optional[bool] = None    # None: compiled on TPU, else the
+    #                                     Pallas interpreter
     check: bool = True                  # compare against the ref oracle
     use_tuned: bool = True              # consult the tuning registry
     chip: Optional[str] = None          # provenance chip (default: TARGET)
     registry: Optional[Registry] = None
     emit: Optional[Callable[[BenchResult], None]] = None  # streaming hook
 
+    def __post_init__(self):
+        self.interpret = ops.resolve_interpret(self.interpret)
+
     def resolved_chip(self) -> str:
-        return self.chip or hardware.TARGET.name
+        """The provenance / tuning-lookup chip: ``chip`` if set, else the
+        attached TPU as its ``device_kind`` names it, else (off-chip) the
+        target the tuning registry is keyed by."""
+        if self.chip:
+            return self.chip
+        if jax.default_backend() == "tpu":
+            return hardware.chip_for_device_kind(
+                jax.devices()[0].device_kind).name
+        return hardware.TARGET.name
 
 
 def new_report() -> BenchReport:

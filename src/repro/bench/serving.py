@@ -54,7 +54,7 @@ def run_serve_scenario(sc: ServeScenario, opts=None) -> BenchResult:
     ``chip`` and ``emit`` apply; serving always runs compiled-for-host)."""
     from ..configs import get_smoke_config
     from ..distributed.sharding import split_tree
-    from ..launch.serve import Request, ServingLoop
+    from ..launch.serve import Request, ServingLoop, slot_rows
     from ..models import build_model
 
     w = sc.workload
@@ -62,13 +62,8 @@ def run_serve_scenario(sc: ServeScenario, opts=None) -> BenchResult:
     model = build_model(cfg)
     params, _ = split_tree(model.init(jax.random.PRNGKey(0)))
 
-    from ..serve import next_pow2
     trace = _trace_requests(w, cfg.vocab)
-    # monolithic prefill books max(next_pow2(prompt), prompt + max_new)
-    # rows per slot, so the per-slot cap must cover the pow2 bucket of the
-    # longest prompt (shared-prefix prompts push past the next boundary)
-    max_new_hi = max(max(next_pow2(len(r.prompt)),
-                         len(r.prompt) + r.max_new) for r in trace)
+    block_len = int(w.get("block_len", 8))
 
     def build_loop(prefix_cache, chunk_tokens=None):
         return ServingLoop(
@@ -76,8 +71,7 @@ def run_serve_scenario(sc: ServeScenario, opts=None) -> BenchResult:
             seed=int(w.get("seed", 0)),
             max_new=max(r.max_new for r in trace),
             scheduler=w.get("scheduler", "continuous"),
-            block_len=int(w.get("block_len", 8)),
-            max_seq=max_new_hi + int(w.get("block_len", 8)),
+            block_len=block_len, max_seq=slot_rows(trace, block_len),
             chunk_tokens=(w.get("chunk_tokens") if chunk_tokens is None
                           else chunk_tokens),
             prefix_cache=prefix_cache)
@@ -152,9 +146,8 @@ def run_serve_scenario(sc: ServeScenario, opts=None) -> BenchResult:
             span.attrs["us_median"] = metrics["us_median"]
             span.attrs["tokens_per_s"] = metrics["tokens_per_s"]
 
-    from ..core import hardware
-    chip = (opts.resolved_chip() if opts is not None
-            else hardware.TARGET.name)
+    from .runner import RunOptions
+    chip = (opts or RunOptions()).resolved_chip()
     result = BenchResult(
         scenario=sc.name, kernel=sc.kernel, shape=list(sc.shape),
         dtype=cfg.dtype, strategy=loop.scheduler_kind, chip=chip,

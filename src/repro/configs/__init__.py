@@ -29,8 +29,9 @@ def _mod(name: str):
     return importlib.import_module(f".{_MODULES[name]}", __package__)
 
 
-def get_config(name: str) -> ArchConfig:
-    return _mod(name).CONFIG
+def get_config(name: str, *, smoke: bool = False) -> ArchConfig:
+    """The published config of ``name`` (its smoke preset if ``smoke``)."""
+    return _mod(name).SMOKE if smoke else _mod(name).CONFIG
 
 
 def get_smoke_config(name: str) -> ArchConfig:

@@ -57,15 +57,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams around 0.5; kernels
-# build their compiler_params through this alias so either version works.
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
+from .hardware import VMEM_BYTES
 
 
-def compiler_params(*, dimension_semantics: Tuple[str, ...]):
-    return CompilerParams(dimension_semantics=dimension_semantics)
+#: Mosaic's default scoped-VMEM budget per kernel on v5e.  A kernel whose
+#: scratch needs more must raise ``vmem_limit_bytes`` or it fails to compile.
+DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+#: lanes in a vreg: the minor-dim tile every unmasked slice and DMA of a
+#: 32-bit array must be aligned to
+LANES = 128
+
+
+def lane_pad(n: int) -> int:
+    """``n`` rounded up to a whole number of lanes."""
+    return -(-n // LANES) * LANES
+
+
+def vmem_bytes(scratch_shapes: Sequence[Any]) -> int:
+    """Bytes of VMEM the ``pltpu.VMEM`` entries of ``scratch_shapes`` take
+    (semaphores are not VMEM and count zero)."""
+    total = 0
+    for s in scratch_shapes:
+        if getattr(s, "memory_space", None) == pltpu.VMEM:
+            n = 1
+            for d in s.shape:
+                n *= d
+            total += n * jnp.dtype(s.dtype).itemsize
+    return total
+
+
+def compiler_params(*, dimension_semantics: Tuple[str, ...],
+                    scratch_shapes: Sequence[Any] = ()):
+    """Mosaic compiler params for a kernel with ``scratch_shapes``.  When
+    the scratch takes more than half the default scoped VMEM (the rest
+    holds Mosaic's own temporaries, such as tiles loaded as values), the
+    limit is raised to the scratch plus one default budget of headroom;
+    scratch beyond the chip's VMEM is refused here."""
+    need = vmem_bytes(scratch_shapes)
+    if need > VMEM_BYTES:
+        raise ValueError(f"kernel scratch needs {need} bytes of VMEM, more "
+                         f"than the {VMEM_BYTES} the chip has; use "
+                         "smaller tiles")
+    limit = None
+    if need > DEFAULT_SCOPED_VMEM // 2:
+        limit = min(need + DEFAULT_SCOPED_VMEM, VMEM_BYTES)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=limit)
 
 
 class Strategy(enum.Enum):

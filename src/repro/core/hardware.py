@@ -122,3 +122,25 @@ def get_chip(name: str) -> Chip:
         return CATALOG[name]
     except KeyError:
         raise KeyError(f"unknown chip {name!r}; known: {sorted(CATALOG)}") from None
+
+
+#: ``jax.devices()[0].device_kind`` -> catalog chip name, for the chips a
+#: run can be attached to.  JAX calls v5e "TPU v5 lite" and v5p "TPU v5".
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v2": "TPUv2",
+    "TPU v3": "TPUv3",
+    "TPU v4": "TPUv4",
+    "TPU v5 lite": "TPUv5e",
+    "TPU v5": "TPUv5p",
+}
+
+
+def chip_for_device_kind(kind: str) -> Chip:
+    """The catalog chip a device reports itself as.  An unknown kind is an
+    error: a run is never labelled, or given peaks, of a chip it did not
+    run on."""
+    try:
+        return CATALOG[DEVICE_KINDS[kind]]
+    except KeyError:
+        raise KeyError(f"unknown device_kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)}") from None

@@ -485,16 +485,6 @@ def cost_with_loops(compiled) -> Cost:
     return analyze_hlo(compiled.as_text())
 
 
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalised across jax versions: older jax
-    returns a one-element list of per-device dicts, newer jax the dict
-    itself.  Always returns the (single-program) dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
-
 # ---------------------------------------------------------------------------
 # Profiling: weighted top ops (the dry-run "profile" — there is no wall-clock
 # trace on this host, so §Perf iterations read this instead)

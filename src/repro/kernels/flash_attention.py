@@ -20,7 +20,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.async_pipeline import (PipelineSpec, Strategy, TileStream,
                                    as_spec, compiler_params, emit,
-                                   scratch_for)
+                                   lane_pad, scratch_for)
 
 NEG_INF = -1e30
 
@@ -110,38 +110,47 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            spec: PipelineSpec = PipelineSpec(),
                            bq: int = 128, bk: int = 128,
                            interpret: bool = False) -> jax.Array:
-    """q: (H, S, D), k/v: (KVH, S, D) -> (H, S, D) fp32."""
+    """q: (H, S, D), k/v: (KVH, S, D) -> (H, S, D) fp32.  A head_dim that
+    is not a lane multiple is zero-padded to one: the zero columns add
+    nothing to q.k and produce zero output columns, which are sliced off."""
     spec = as_spec(spec)
-    h, s, d = q.shape
+    h, s, d_in = q.shape
     kvh = k.shape[0]
     assert h % kvh == 0, (h, kvh)
     if s % bq or s % bk:
         raise ValueError(f"seq {s} must divide bq={bq}, bk={bk}")
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    scale = scale if scale is not None else 1.0 / (d_in ** 0.5)
+    d = lane_pad(d_in)
+    if d != d_in:
+        pad = ((0, 0), (0, 0), (0, d - d_in))
+        q, k, v = (jnp.pad(t, pad) for t in (q, k, v))
     k_buf, k_sems, k_stage = scratch_for(spec, (bk, d), k.dtype)
     v_buf, v_sems, v_stage = scratch_for(spec, (bk, d), v.dtype)
     kernel = functools.partial(
         _flash_kernel, spec=spec, bq=bq, bk=bk, head_dim=d,
         q_heads_per_kv=h // kvh, causal=causal, window=window, scale=scale,
         n_kv_tiles_max=s // bk)
-    return pl.pallas_call(
+    scratch = [
+        pltpu.VMEM((bq, d), q.dtype),
+        k_buf, v_buf,
+        k_stage, v_stage,
+        pltpu.VMEM((bq, d), jnp.float32),    # acc
+        pltpu.VMEM((bq, 1), jnp.float32),    # running max
+        pltpu.VMEM((bq, 1), jnp.float32),    # running denom
+        pltpu.SemaphoreType.DMA,
+        k_sems, v_sems,
+        pltpu.SemaphoreType.DMA,
+    ]
+    out = pl.pallas_call(
         kernel,
         grid=(h, s // bq),
         out_shape=jax.ShapeDtypeStruct((h, s, d), jnp.float32),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), q.dtype),
-            k_buf, v_buf,
-            k_stage, v_stage,
-            pltpu.VMEM((bq, d), jnp.float32),    # acc
-            pltpu.VMEM((bq, 1), jnp.float32),    # running max
-            pltpu.VMEM((bq, 1), jnp.float32),    # running denom
-            pltpu.SemaphoreType.DMA,
-            k_sems, v_sems,
-            pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            scratch_shapes=scratch),
     )(q, k, v)
+    return out[..., :d_in]

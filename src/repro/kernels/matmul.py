@@ -40,8 +40,14 @@ def _matmul_kernel(a_hbm, b_hbm, o_hbm, a_buf, b_buf, acc, a_stage, b_stage,
 
     acc[...] = jnp.zeros_like(acc)
 
+    # bf16 products are exact in the f32 accumulator at any precision, and
+    # Mosaic rejects a bf16 dot asked for HIGHEST: pin DEFAULT for narrow
+    # inputs so a process-wide "highest" setting cannot break the kernel
+    precision = (None if a_buf.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+
     def mac(a_tile, b_tile):
-        acc[...] += jnp.dot(a_tile, b_tile,
+        acc[...] += jnp.dot(a_tile, b_tile, precision=precision,
                             preferred_element_type=jnp.float32)
 
     if spec.strategy == Strategy.DROP_OFF:
@@ -75,6 +81,9 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
     b_buf, b_sems, b_stage = scratch_for(spec, (bk, bn), b.dtype)
     kernel = functools.partial(
         _matmul_kernel, spec=spec, n_k=n_k, bm=bm, bk=bk, bn=bn)
+    scratch = [a_buf, b_buf,
+               pltpu.VMEM((bm, bn), jnp.float32),   # accumulator
+               a_stage, b_stage, a_sems, b_sems, pltpu.SemaphoreType.DMA]
     return pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn),
@@ -82,17 +91,11 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            a_buf, b_buf,
-            pltpu.VMEM((bm, bn), jnp.float32),   # accumulator
-            a_stage,
-            b_stage,
-            a_sems, b_sems,
-            pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=compiler_params(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "parallel"),
+            scratch_shapes=scratch),
     )(a, b)
 
 

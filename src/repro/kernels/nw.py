@@ -24,20 +24,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.async_pipeline import (PipelineSpec, Strategy, TileStream,
-                                   WriteBack, as_spec, emit, scratch_for,
-                                   writeback_scratch)
+                                   WriteBack, as_spec, compiler_params, emit,
+                                   lane_pad, scratch_for, writeback_scratch)
 
 NEG = -1e30
 
 
-def _cummax(x):
+def _shift_right(x, shift: int, lane):
+    """x shifted ``shift`` lanes to the right along the last axis, NEG
+    shifted in.  A lane rotation plus a mask: no unaligned lane slices."""
+    return jnp.where(lane >= shift, pltpu.roll(x, shift, x.ndim - 1), NEG)
+
+
+def _cummax(x, lane):
     """Hillis-Steele inclusive max-scan along the last axis (static width)."""
     n = x.shape[-1]
     shift = 1
     while shift < n:
-        shifted = jnp.concatenate(
-            [jnp.full_like(x[..., :shift], NEG), x[..., :-shift]], axis=-1)
-        x = jnp.maximum(x, shifted)
+        x = jnp.maximum(x, _shift_right(x, shift, lane))
         shift *= 2
     return x
 
@@ -47,8 +51,9 @@ def _nw_kernel(scores_hbm, o_hbm, state, row_buf, stage, sems, out_buf,
                *, spec: PipelineSpec, n_tiles: int, tile_rows: int, n: int,
                width: int, penalty: float):
     # state = DP row of length n+1 (padded to `width`); row 0 is -j*p
-    j = jax.lax.broadcasted_iota(jnp.float32, (1, width), 1)
-    valid = j <= n
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    j = lane.astype(jnp.float32)
+    valid = lane <= n
     state[...] = jnp.where(valid, -penalty * j, NEG)
 
     stream = TileStream(
@@ -66,12 +71,11 @@ def _nw_kernel(scores_hbm, o_hbm, state, row_buf, stage, sems, out_buf,
         for r in range(tile_rows):                  # carried row recurrence
             row_idx = (i * tile_rows + r + 1)
             prev = state[...]
-            prev_shift = jnp.concatenate(
-                [jnp.full_like(prev[:, :1], NEG), prev[:, :-1]], axis=1)
+            prev_shift = _shift_right(prev, 1, lane)
             c = jnp.maximum(prev_shift + tile[r:r + 1, :], prev - penalty)
-            c = jnp.where(j == 0, -penalty * row_idx, c)
+            c = jnp.where(lane == 0, -penalty * row_idx, c)
             t = jnp.where(valid, c + penalty * j, NEG)
-            new = jnp.where(valid, _cummax(t) - penalty * j, NEG)
+            new = jnp.where(valid, _cummax(t, lane) - penalty * j, NEG)
             state[...] = new
             rows.append(new)
         wb.push(i, jnp.concatenate(rows, axis=0))
@@ -96,7 +100,7 @@ def nw_pallas(seq_scores: jax.Array, penalty: int, *,
     n = seq_scores.shape[0]
     if n % tile_rows:
         raise ValueError(f"n={n} must divide tile_rows={tile_rows}")
-    width = ((n + 1 + 127) // 128) * 128
+    width = lane_pad(n + 1)
     # align scores so that column j of the padded row holds s[i-1, j-1]
     scores = jnp.pad(seq_scores.astype(jnp.float32),
                      ((0, 0), (1, width - n - 1)))
@@ -104,6 +108,9 @@ def nw_pallas(seq_scores: jax.Array, penalty: int, *,
     row_buf, sems, stage = scratch_for(spec, (tile_rows, width), jnp.float32)
     out_buf, out_sems = writeback_scratch(spec, (tile_rows, width),
                                           jnp.float32)
+    scratch = [pltpu.VMEM((1, width), jnp.float32),     # DP row state
+               row_buf, stage, sems, out_buf, out_sems,
+               pltpu.SemaphoreType.DMA]
     kernel = functools.partial(
         _nw_kernel, spec=spec, n_tiles=n_tiles, tile_rows=tile_rows,
         n=n, width=width, penalty=float(penalty))
@@ -112,16 +119,10 @@ def nw_pallas(seq_scores: jax.Array, penalty: int, *,
         out_shape=jax.ShapeDtypeStruct((n, width), jnp.float32),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((1, width), jnp.float32),           # DP row state
-            row_buf,
-            stage,
-            sems,
-            out_buf,
-            out_sems,
-            pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
+        compiler_params=compiler_params(dimension_semantics=(),
+                                        scratch_shapes=scratch),
     )(scores)
     top = -penalty * jnp.arange(n + 1, dtype=jnp.float32)[None, :]
     return jnp.concatenate([top, table[:, :n + 1]], axis=0)

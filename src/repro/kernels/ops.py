@@ -6,9 +6,9 @@ pipeline-shape axes ``depth`` / ``wait_group`` (and ``out_depth`` for the
 kernels with a write-back ring), is jitted with the structural arguments
 static, and has a matching oracle in ``ref.py``.  The flat keywords are
 assembled into a ``core.async_pipeline.PipelineSpec`` inside the jitted
-implementation.  ``interpret=True`` (default on this CPU container) runs the
-kernel bodies in Python via the Pallas interpreter; on a real TPU pass
-``interpret=False``.
+implementation.  ``interpret`` defaults to the backend's mode
+(``resolve_interpret``): compiled Mosaic kernels on a TPU, the Pallas
+interpreter on the CPU, where nothing else can run them.
 
 Config constants are NOT hard-coded per call site: each kernel's tunable
 parameters live in ``KERNEL_DEFAULTS`` and any omitted (None) keyword falls
@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,7 @@ __all__ = [
     "stream", "hotspot", "pathfinder", "nw", "lud", "matmul",
     "flash_attention", "Strategy", "KERNEL_DEFAULTS", "default_config",
     "seed_default_config", "set_default_config", "reset_default_configs",
+    "resolve_interpret",
 ]
 
 
@@ -97,6 +98,15 @@ def reset_default_configs() -> None:
         KERNEL_DEFAULTS[k] = dict(v)
 
 
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``interpret`` if given, else the backend's mode: compiled on a TPU,
+    the Pallas interpreter anywhere else.  The one place that default is
+    decided, for the wrappers below and for ``bench.runner.RunOptions``."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
 def _resolve(kernel: str, **given: Any) -> Dict[str, Any]:
     cfg = KERNEL_DEFAULTS[kernel]
     return {k: (cfg[k] if v is None else v) for k, v in given.items()}
@@ -142,7 +152,8 @@ def _stream(x, *, iters, strategy, tile_rows, n_tiles, depth, wait_group,
 
 
 def stream(x, *, iters=1, strategy=None, tile_rows=None, n_tiles=None,
-           depth=None, wait_group=None, out_depth=None, interpret=True):
+           depth=None, wait_group=None, out_depth=None, interpret=None):
+    interpret = resolve_interpret(interpret)
     return _with_seed_fallback(
         "stream", dict(strategy=strategy, tile_rows=tile_rows,
                        n_tiles=n_tiles, depth=depth, wait_group=wait_group,
@@ -164,7 +175,8 @@ def _hotspot(temp, power, *, iters, strategy, tile_rows, depth, wait_group,
 
 def hotspot(temp, power, *, iters=1, strategy=None, tile_rows=None,
             depth=None, wait_group=None, out_depth=None, grid=1,
-            interpret=True):
+            interpret=None):
+    interpret = resolve_interpret(interpret)
     return _with_seed_fallback(
         "hotspot", dict(strategy=strategy, tile_rows=tile_rows, depth=depth,
                         wait_group=wait_group, out_depth=out_depth),
@@ -182,7 +194,8 @@ def _pathfinder(wall, *, strategy, tile_rows, depth, wait_group, interpret):
 
 
 def pathfinder(wall, *, strategy=None, tile_rows=None, depth=None,
-               wait_group=None, interpret=True):
+               wait_group=None, interpret=None):
+    interpret = resolve_interpret(interpret)
     return _with_seed_fallback(
         "pathfinder", dict(strategy=strategy, tile_rows=tile_rows,
                            depth=depth, wait_group=wait_group),
@@ -201,7 +214,8 @@ def _nw_jit(seq_scores, *, penalty, strategy, tile_rows, depth, wait_group,
 
 
 def nw(seq_scores, *, penalty=10, strategy=None, tile_rows=None, depth=None,
-       wait_group=None, out_depth=None, interpret=True):
+       wait_group=None, out_depth=None, interpret=None):
+    interpret = resolve_interpret(interpret)
     return _with_seed_fallback(
         "nw", dict(strategy=strategy, tile_rows=tile_rows, depth=depth,
                    wait_group=wait_group, out_depth=out_depth),
@@ -218,7 +232,8 @@ def _lud_jit(a, *, bs, strategy, depth, wait_group, out_depth, interpret):
 
 
 def lud(a, *, bs=None, strategy=None, depth=None, wait_group=None,
-        out_depth=None, interpret=True):
+        out_depth=None, interpret=None):
+    interpret = resolve_interpret(interpret)
     return _with_seed_fallback(
         "lud", dict(bs=bs, strategy=strategy, depth=depth,
                     wait_group=wait_group, out_depth=out_depth),
@@ -235,7 +250,8 @@ def _matmul(a, b, *, strategy, bm, bk, bn, depth, wait_group, interpret):
 
 
 def matmul(a, b, *, strategy=None, bm=None, bk=None, bn=None, depth=None,
-           wait_group=None, interpret=True):
+           wait_group=None, interpret=None):
+    interpret = resolve_interpret(interpret)
     return _with_seed_fallback(
         "matmul", dict(strategy=strategy, bm=bm, bk=bk, bn=bn, depth=depth,
                        wait_group=wait_group),
@@ -259,8 +275,9 @@ def _flash_jit(q, k, v, *, causal, window, scale, strategy, bq, bk, depth,
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
                     strategy=None, bq=None, bk=None, depth=None,
-                    wait_group=None, interpret=True):
+                    wait_group=None, interpret=None):
     """q: (..., H, S, D), k/v: (..., KVH, S, D); leading dims are vmapped."""
+    interpret = resolve_interpret(interpret)
     return _with_seed_fallback(
         "flash_attention", dict(strategy=strategy, bq=bq, bk=bk,
                                 depth=depth, wait_group=wait_group),

@@ -16,37 +16,54 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.async_pipeline import (PipelineSpec, Strategy, TileStream,
-                                   as_spec, emit, scratch_for)
+                                   as_spec, compiler_params, emit,
+                                   lane_pad, scratch_for)
 
 
-def _min3(prev):
-    # prev: (1, cols); neighbours clamp at the edges
-    left = jnp.concatenate([prev[:, :1], prev[:, :-1]], axis=1)
-    right = jnp.concatenate([prev[:, 1:], prev[:, -1:]], axis=1)
+def _min3(prev, cols: int):
+    # prev: (1, width); neighbours clamp at the real edges (col 0 and
+    # cols-1); lanes past cols are padding and never feed a real column
+    width = prev.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, prev.shape, 1)
+    left = jnp.where(col == 0, prev, pltpu.roll(prev, 1, 1))
+    right = jnp.where(col == cols - 1, prev, pltpu.roll(prev, width - 1, 1))
     return jnp.minimum(prev, jnp.minimum(left, right))
 
 
-def _pathfinder_kernel(wall_hbm, o_hbm, state, row_buf, stage, sems, out_sem,
-                       *, spec: PipelineSpec, n_tiles: int, tile_rows: int):
-    # row 0 initialises the DP state
-    init = pltpu.make_async_copy(wall_hbm.at[pl.ds(0, 1), :], state, out_sem)
+def _pathfinder_kernel(first_hbm, wall_hbm, o_hbm, state, row_buf, stage,
+                       sems, out_sem, *, spec: PipelineSpec, n_tiles: int,
+                       tile_rows: int, n_rows: int, cols: int):
+    # row 0 initialises the DP state (its own (1, width) array, so the
+    # copy is a whole-array DMA)
+    init = pltpu.make_async_copy(first_hbm, state, out_sem)
     init.start()
     init.wait()
 
     stream = TileStream(
         hbm=wall_hbm, vmem=row_buf, sem=sems,
-        index=lambda i: (pl.ds(1 + i * tile_rows, tile_rows), slice(None)),
+        index=lambda i: (pl.ds(i * tile_rows, tile_rows), slice(None)),
         depth=spec.ring_depth)
 
-    def fold(tile):
-        for r in range(tile_rows):          # static unroll; carried dependency
-            state[...] = tile[r:r + 1, :] + _min3(state[...])
+    def step(i, r, row):
+        new = row + _min3(state[...], cols)
+        if n_rows % tile_rows:              # the last tile has pad rows
+            new = jnp.where(i * tile_rows + r < n_rows, new, state[...])
+        state[...] = new
 
     if spec.strategy == Strategy.DROP_OFF:
-        emit(spec, [stream], n_tiles, lambda i, vals: fold(vals[0]))
+        # the tile is held as values: static unroll over its rows
+        def compute_value(i, vals):
+            for r in range(tile_rows):
+                step(i, r, vals[0][r:r + 1, :])
+        emit(spec, [stream], n_tiles, compute_value)
     else:
+        # the tile stays in VMEM: one row loaded per loop step, so the
+        # compiled body is one row's worth of code, not tile_rows of it
         def compute(i, bufs):
-            fold(bufs[0][...])
+            def body(r, _):
+                step(i, r, bufs[0][pl.ds(r, 1), :])
+                return ()
+            jax.lax.fori_loop(0, tile_rows, body, ())
         emit(spec, [stream], n_tiles, compute, staging=[stage])
 
     out = pltpu.make_async_copy(state, o_hbm, out_sem)
@@ -58,27 +75,33 @@ def pathfinder_pallas(wall: jax.Array, *,
                       spec: PipelineSpec = PipelineSpec(Strategy.DROP_OFF),
                       tile_rows: int = 8,
                       interpret: bool = False) -> jax.Array:
-    """wall: (rows, cols); rows-1 must divide by tile_rows.  Returns (1, cols)
-    final DP row."""
+    """wall: (rows, cols), any rows >= 2 and cols.  Returns the (1, cols)
+    final DP row.  Columns are padded to a lane multiple and the rows after
+    the first to a tile multiple; pad rows leave the DP state unchanged."""
     spec = as_spec(spec)
     rows, cols = wall.shape
-    if (rows - 1) % tile_rows:
-        raise ValueError(f"rows-1={rows-1} must divide tile_rows={tile_rows}")
-    n_tiles = (rows - 1) // tile_rows
-    row_buf, sems, stage = scratch_for(spec, (tile_rows, cols), wall.dtype)
+    if rows < 2:
+        raise ValueError(f"pathfinder needs at least 2 rows, got {rows}")
+    n_rows = rows - 1
+    n_tiles = -(-n_rows // tile_rows)
+    width = lane_pad(cols)
+    first = jnp.pad(wall[:1], ((0, 0), (0, width - cols)))
+    body = jnp.pad(wall[1:], ((0, n_tiles * tile_rows - n_rows),
+                              (0, width - cols)))
+    row_buf, sems, stage = scratch_for(spec, (tile_rows, width), wall.dtype)
+    scratch = [pltpu.VMEM((1, width), wall.dtype),      # DP state
+               row_buf, stage, sems, pltpu.SemaphoreType.DMA]
     kernel = functools.partial(
-        _pathfinder_kernel, spec=spec, n_tiles=n_tiles, tile_rows=tile_rows)
-    return pl.pallas_call(
+        _pathfinder_kernel, spec=spec, n_tiles=n_tiles, tile_rows=tile_rows,
+        n_rows=n_rows, cols=cols)
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((1, cols), wall.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_shape=jax.ShapeDtypeStruct((1, width), wall.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((1, cols), wall.dtype),          # DP state
-            row_buf,
-            stage,
-            sems,
-            pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(wall)
+        compiler_params=compiler_params(dimension_semantics=(),
+                                        scratch_shapes=scratch),
+    )(first, body)
+    return out[:, :cols]

@@ -76,16 +76,17 @@ def stream_pallas(x: jax.Array, *, iters: int = 1,
     kernel = functools.partial(
         _stream_kernel, spec=spec, n_tiles=n_tiles,
         tile_rows=tile_rows, iters=iters)
+    scratch = [in_buf, out_buf, stage, in_sems, out_sems]
     return pl.pallas_call(
         kernel,
         grid=(grid,),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[in_buf, out_buf, stage, in_sems, out_sems],
+        scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=compiler_params(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",), scratch_shapes=scratch),
     )(x)
 
 

@@ -225,10 +225,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     model = build_model(cfg)
     t0 = time.time()
-    # jax >= 0.6 activates a mesh via jax.set_mesh; on 0.4.x the Mesh
-    # object itself is the context manager
-    _set_mesh = getattr(jax, "set_mesh", None)
-    with (_set_mesh(mesh) if _set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         mode = shape.mode
         rules = build_rules(mesh, cfg, shape, mode, run)
         with shd.use_rules(rules):

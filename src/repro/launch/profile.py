@@ -62,10 +62,7 @@ def compile_cell(arch: str, shape_name: str):
         and state_gb > 0.5 * (hardware.HBM_BYTES / 2 ** 30)
     run = RunConfig(microbatches=micro, fsdp=fsdp)
     model = build_model(cfg)
-    # jax >= 0.6 activates a mesh via jax.set_mesh; on 0.4.x the Mesh
-    # object itself is the context manager
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh is not None else mesh):
+    with jax.set_mesh(mesh):
         rules = D.build_rules(mesh, cfg, shape, shape.mode, run)
         with shd.use_rules(rules):
             p_shapes, p_axes = D.abstract_params(model)

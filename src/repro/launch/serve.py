@@ -11,7 +11,8 @@ The legacy helpers (``Request``, ``sample``, ``pack_prompts``,
 ``mask_padded_cache``, ``build_serve_fns``) are re-exported from
 ``repro.serve`` so existing imports keep working.
 
-Run as a script it serves a reduced model locally:
+Run as a script it serves the published config with random weights on the
+default device (``--smoke`` serves the 2-layer preset instead):
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --requests 4
 """
 from __future__ import annotations
@@ -24,18 +25,38 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from ..core.compile_cache import use_persistent_cache
 from ..core.config import ArchConfig
+from ..distributed.sharding import split_tree
 from ..models import build_model
 from ..obs import metrics as obs_metrics
 from ..obs.trace import get_tracer
 from ..serve import (ARRIVALS, CohortScheduler, ContinuousScheduler,
                      Request, build_serve_fns, make_trace,
-                     mask_padded_cache, pack_prompts, sample)
+                     mask_padded_cache, next_pow2, pack_prompts, sample)
 
-__all__ = ["Request", "ServingLoop", "build_serve_fns", "main",
-           "mask_padded_cache", "pack_prompts", "sample"]
+__all__ = ["Request", "ServingLoop", "build_serve_fns", "init_params",
+           "main", "mask_padded_cache", "pack_prompts", "sample",
+           "slot_rows"]
 
 log = logging.getLogger("repro.serve")
+
+
+def init_params(cfg: ArchConfig, seed: int = 0):
+    """Random parameters for ``cfg`` from ``seed``, made on the default
+    device by one jitted program (no host copy of the weights)."""
+    model = build_model(cfg)
+    return jax.jit(lambda key: split_tree(model.init(key))[0])(
+        jax.random.PRNGKey(seed))
+
+
+def slot_rows(requests: List[Request], block_len: int) -> int:
+    """Cache rows one slot needs to admit any of ``requests`` on either
+    prefill path: the larger of the monolithic path's pow2 prefill bucket
+    and prompt + max_new, plus one block.  ``ServingLoop``'s ``max_seq``;
+    the arena then holds ``batch`` such slots."""
+    return max(max(next_pow2(len(r.prompt)), len(r.prompt) + r.max_new)
+               for r in requests) + block_len
 
 
 class ServingLoop:
@@ -101,6 +122,9 @@ class ServingLoop:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's 2-layer smoke preset instead of "
+                         "its published config")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -150,23 +174,15 @@ def main(argv=None):
                     help="enable span tracing; write the span JSONL to PATH")
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    use_persistent_cache()
     from ..tuning import apply_tuned_kernel_defaults
     apply_tuned_kernel_defaults(args.tuning_registry)
     if args.trace:
         get_tracer().enable()
 
-    from ..configs import get_smoke_config
-    from ..distributed.sharding import split_tree
-    cfg = get_smoke_config(args.arch)
-    model = build_model(cfg)
-    params, _ = split_tree(model.init(jax.random.PRNGKey(0)))
-    loop = ServingLoop(cfg, params, batch=args.batch, max_new=args.max_new,
-                       seed=args.seed, scheduler=args.scheduler,
-                       block_len=args.block_len,
-                       max_seq=(args.prompt_len + args.prefix_len
-                                + args.max_new + args.block_len),
-                       chunk_tokens=args.chunk_tokens,
-                       prefix_cache=args.prefix_cache)
+    from ..configs import get_config
+    cfg = get_config(args.arch, smoke=args.smoke)
+    params = init_params(cfg)
     if args.arrival == "none":
         rng = np.random.default_rng(args.seed)
         lens = (rng.integers(4, args.prompt_len + 1, args.requests)
@@ -188,6 +204,12 @@ def main(argv=None):
                           max_new=(args.max_new, args.max_new),
                           prefix_len=args.prefix_len,
                           prefix_group=args.prefix_group)
+    loop = ServingLoop(cfg, params, batch=args.batch, max_new=args.max_new,
+                       seed=args.seed, scheduler=args.scheduler,
+                       block_len=args.block_len,
+                       max_seq=slot_rows(reqs, args.block_len),
+                       chunk_tokens=args.chunk_tokens,
+                       prefix_cache=args.prefix_cache)
     t0 = time.time()
     results = loop.run(reqs, max_steps=args.max_new)
     dt = time.time() - t0

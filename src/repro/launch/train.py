@@ -6,8 +6,9 @@ with gradient-accumulation microbatching (activation memory ~ 1/A), optional
 bf16 gradient-accumulator compression (the cross-replica reduce then moves
 half the bytes), remat-inside-scan, and ZeRO-1 moment sharding constraints.
 
-Run as a script it trains a reduced model end-to-end on the local device:
-    PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --steps 50
+Run as a script it trains the published config on the local device
+(``--smoke`` trains the 2-layer preset instead):
+    PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --smoke --steps 50
 """
 from __future__ import annotations
 
@@ -20,6 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..core.compile_cache import use_persistent_cache
 from ..core.config import ArchConfig, RunConfig
 from ..distributed import sharding as shd
 from ..distributed.fault_tolerance import (PreemptionGuard, StepStats,
@@ -175,25 +177,32 @@ def train_loop(cfg: ArchConfig, run: RunConfig, *, steps: int,
     return params, opt_state, history
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="train the arch's 2-layer smoke preset instead of "
+                         "its published config")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--tuning-registry", default=None,
                     help="autotuning registry JSON (default "
                          "./tuning_registry.json)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    use_persistent_cache()
     from ..tuning import apply_tuned_kernel_defaults
     apply_tuned_kernel_defaults(args.tuning_registry)
 
-    from ..configs import get_config, get_smoke_config
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    from ..configs import get_config
+    cfg = get_config(args.arch, smoke=args.smoke)
     run = RunConfig(total_steps=args.steps, warmup_steps=max(args.steps // 10, 1))
     _, _, history = train_loop(cfg, run, steps=args.steps, batch=args.batch,
                                seq=args.seq, ckpt_dir=args.ckpt,

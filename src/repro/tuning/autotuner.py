@@ -254,7 +254,7 @@ def apply_tuned_kernel_defaults(registry_path: Optional[str] = None
     elsewhere), and installs the winners as kernel defaults.  A missing or
     stale registry is a silent no-op — startup must succeed cold."""
     try:
-        interpret = jax.default_backend() != "tpu"
+        interpret = ops.resolve_interpret()
         applied = apply_registry_defaults(Registry(registry_path),
                                           interpret=interpret)
         if applied:

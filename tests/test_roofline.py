@@ -7,8 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import hardware, roofline
-from repro.core.hlo_cost import (analyze_hlo, cost_with_loops,
-                                  xla_cost_analysis)
+from repro.core.hlo_cost import analyze_hlo, cost_with_loops
 
 
 def test_scan_flops_are_trip_scaled():
@@ -25,7 +24,7 @@ def test_scan_flops_are_trip_scaled():
     analytic = 2 * 8 * 32 * 128 * 128
     assert abs(ours.flops - analytic) / analytic < 0.05
     # XLA's own analysis undercounts by ~the trip count — the motivating bug
-    xla = xla_cost_analysis(compiled).get("flops", 0)
+    xla = compiled.cost_analysis().get("flops", 0)
     assert xla < analytic / 4
 
 
@@ -35,7 +34,7 @@ def test_nonscan_flops_match_xla():
     s = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     compiled = jax.jit(g).lower(s, s).compile()
     ours = cost_with_loops(compiled)
-    xla = xla_cost_analysis(compiled).get("flops", 0)
+    xla = compiled.cost_analysis().get("flops", 0)
     assert abs(ours.flops - xla) / xla < 0.05
 
 
