@@ -15,8 +15,10 @@ Phases:
                seed) serves four requests through ``ServingLoop``, first
                with monolithic prefill, then with chunked prefill and
                prefix sharing; every request must return exactly its
-               ``max_new`` in-vocab tokens, equal to a batch-1 run of the
-               same scheduler up to a near-tie (``MARGIN_BOUND``).
+               ``max_new`` in-vocab tokens, and its logits must equal
+               those of a batch-1 run of the same scheduler within
+               ``LOGIT_TOL`` at every step up to and including the first
+               token where the two runs differ.
   (d) end      the last stdout line is one JSON object naming the device.
 
 Any failure raises, so the script exits non-zero and prints no result.  It
@@ -41,12 +43,18 @@ MAX_NEW = 32
 BATCH = 4
 BLOCK_LEN = 16
 CHUNK_TOKENS = 128
-#: a batched and a batch-1 dispatch may round bf16 activations differently;
-#: logits here have std ~0.8 (embedding std 0.02 x sqrt(1536)), and such
-#: rounding moves them by a few 1e-2 (on v5e, divergences came at margins
-#: of 3e-4 to 2.8e-2).  A token that differs from the batch-1 run is
-#: accepted only where the top two logits were within this bound there.
-MARGIN_BOUND = 0.05
+#: a batched and a batch-1 decode of the same request see the same inputs
+#: up to the first token where they differ, but their dispatches have other
+#: shapes and may round bf16 activations differently.  This bounds the
+#: largest logit difference over the vocabulary at any such step.  Logits
+#: here have std ~0.8 (embedding std 0.02 x sqrt(1536)); on v5e, batch
+#: noise flipped tokens at top-2 margins of at most 2.8e-2, so it is of
+#: order 1e-2.  The slot fault tried, a decode position one row stale in
+#: one slot, moved logits by about one std on the CPU smoke preset (0.16
+#: against std ~0.14).  The bound, ~0.3 std, sits between the two.  A token
+#: that differs therefore differs only where the batch-1 logits of the two
+#: tokens were within 2 x LOGIT_TOL: a near-tie, not a fault.
+LOGIT_TOL = 0.25
 
 
 def check_device():
@@ -182,34 +190,24 @@ def _requests(vocab):
             prompts.append(np.concatenate([prefix, tail]))
 
     def fresh():
-        return [Request(uid=i, prompt=p, max_new=MAX_NEW)
+        return [Request(uid=i, prompt=p, max_new=MAX_NEW, logits=[])
                 for i, p in enumerate(prompts)]
     return fresh
 
 
-def _margin(model, params, prompt, tokens):
-    """Top-1 minus top-2 logit where ``tokens`` continues ``prompt``."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    seq = np.concatenate([prompt, np.asarray(tokens, np.int32)])[None]
-    logits, _ = jax.jit(model.prefill)(params, {"tokens": jnp.asarray(seq)})
-    top = jax.lax.top_k(logits[0].astype(jnp.float32), 2)[0]
-    return float(top[0] - top[1])
-
-
 def _serve(label, cfg, params, fresh, **kw):
+    """Serve ``fresh()`` at batch BATCH and at batch 1; returns the two
+    request lists, which hold their tokens and logits."""
     from repro.launch.serve import ServingLoop, slot_rows
 
-    reqs = fresh()
-    rows = slot_rows(reqs, BLOCK_LEN)
     out = {}
     for batch in (BATCH, 1):
+        reqs = fresh()
         loop = ServingLoop(cfg, params, batch=batch, max_new=MAX_NEW,
-                           seed=SEED, block_len=BLOCK_LEN, max_seq=rows,
-                           **kw)
-        out[batch] = loop.run(fresh(), max_steps=MAX_NEW)
+                           seed=SEED, block_len=BLOCK_LEN,
+                           max_seq=slot_rows(reqs, BLOCK_LEN), **kw)
+        loop.run(reqs, max_steps=MAX_NEW)
+        out[batch] = reqs
         if batch == BATCH and loop.prefix_cache:
             hits = loop.scheduler.cache.hit_tokens
             print(f"serve {label}: prefix-cache hit tokens={hits}",
@@ -217,47 +215,65 @@ def _serve(label, cfg, params, fresh, **kw):
             if hits <= 0:
                 raise RuntimeError(f"{label}: the shared prefix was never "
                                    "served from cache")
-    return reqs, out[BATCH], out[1]
+    return out[BATCH], out[1]
+
+
+def _compare(label, got, want, vocab):
+    """Check one request's batched run ``got`` against its batch-1 run
+    ``want``; returns a failure message or None."""
+    import jax.numpy as jnp
+
+    for name, r in (("batched", got), ("solo", want)):
+        toks = r.out_tokens
+        if len(toks) != MAX_NEW or len(r.logits) != MAX_NEW or not all(
+                0 <= t < vocab for t in toks):
+            return (f"{label} req {r.uid} ({name}): {len(toks)} tokens and "
+                    f"{len(r.logits)} logits rows, want {MAX_NEW} in "
+                    f"[0, {vocab})")
+    diff = next((j for j, (a, b) in enumerate(zip(got.out_tokens,
+                                                  want.out_tokens))
+                 if a != b), None)
+    last = MAX_NEW - 1 if diff is None else diff
+    err = float(jnp.max(jnp.abs(
+        jnp.stack(got.logits[:last + 1]).astype(jnp.float32)
+        - jnp.stack(want.logits[:last + 1]).astype(jnp.float32))))
+    line = (f"serve {label} req {got.uid}: prompt={len(got.prompt)} "
+            f"max |logit diff| over steps 0..{last} = {err:.4e} "
+            f"(tol {LOGIT_TOL})")
+    if diff is None:
+        line += f"; all {MAX_NEW} tokens equal to batch-1"
+    else:
+        solo = want.logits[diff].astype(jnp.float32)
+        gap = float(solo[want.out_tokens[diff]] - solo[got.out_tokens[diff]])
+        line += (f"; first differs at step {diff}, where batch-1 ranks its "
+                 f"token {gap:.4f} above the batched one")
+    print(line, flush=True)
+    if not err <= LOGIT_TOL:
+        return (f"{label} req {got.uid}: logits differ from batch-1 by "
+                f"{err:.4e} > {LOGIT_TOL}")
+    return None
 
 
 def check_serving():
     from repro.configs import get_config
     from repro.launch.serve import init_params
-    from repro.models import build_model
 
     cfg = get_config(ARCH)
     params = init_params(cfg, SEED)
-    model = build_model(cfg)
     fresh = _requests(cfg.vocab)
     print(f"serve: {ARCH} layers={cfg.n_layers} d_model={cfg.d_model} "
           f"vocab={cfg.vocab} params={cfg.param_count()}", flush=True)
+    failures = []
     for label, kw in (("monolithic", {}),
                       ("chunked+prefix", dict(chunk_tokens=CHUNK_TOKENS,
                                               prefix_cache=True))):
-        reqs, batched, solo = _serve(label, cfg, params, fresh, **kw)
-        for r in reqs:
-            got, want = batched[r.uid], solo[r.uid]
-            for name, toks in (("batched", got), ("solo", want)):
-                if len(toks) != MAX_NEW or not all(
-                        0 <= t < cfg.vocab for t in toks):
-                    raise RuntimeError(
-                        f"{label} req {r.uid} ({name}): {len(toks)} tokens,"
-                        f" want {MAX_NEW} in [0, {cfg.vocab})")
-            diff = next((j for j, (a, b) in enumerate(zip(got, want))
-                         if a != b), None)
-            if diff is None:
-                print(f"serve {label} req {r.uid}: prompt={len(r.prompt)} "
-                      f"{MAX_NEW} tokens, equal to batch-1", flush=True)
-                continue
-            margin = _margin(model, params, r.prompt, want[:diff])
-            print(f"serve {label} req {r.uid}: prompt={len(r.prompt)} "
-                  f"first differs from batch-1 at step {diff}, top-2 "
-                  f"margin there {margin:.4f} (bound {MARGIN_BOUND})",
-                  flush=True)
-            if not margin < MARGIN_BOUND:
-                raise RuntimeError(
-                    f"{label} req {r.uid}: differs from batch-1 at step "
-                    f"{diff} where the top-2 margin is {margin:.4f}")
+        batched, solo = _serve(label, cfg, params, fresh, **kw)
+        for got, want in zip(batched, solo):
+            msg = _compare(label, got, want, cfg.vocab)
+            if msg:
+                failures.append(msg)
+    if failures:
+        raise RuntimeError("; ".join(failures))
 
 
 def main() -> int:

@@ -12,6 +12,7 @@ loaded band with ``pltpu.roll``, and the left/right edges clamp by masking.
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,6 +81,30 @@ def _hotspot_kernel(tpad_hbm, power_hbm, o_hbm, t_buf, p_buf, out_buf,
     wb.drain(n_tiles)
 
 
+def hotspot_scratch(spec: PipelineSpec, tile_rows: int, cols: int,
+                    dtype) -> list:
+    """The kernel's scratch at the lane-padded width: the halo-band and
+    power rings, the write-back ring, SYNC staging and DMA semaphores."""
+    width = lane_pad(cols)
+    t_buf, t_sems, t_stage = scratch_for(
+        spec, (tile_rows + 2 * HALO, width), dtype)
+    p_buf, p_sems, p_stage = scratch_for(spec, (tile_rows, width), dtype)
+    out_buf, out_sems = writeback_scratch(spec, (tile_rows, width), dtype)
+    return [t_buf, p_buf, out_buf, t_stage, p_stage,
+            t_sems, p_sems, out_sems]
+
+
+def hotspot_flops_bytes(shape, dtype_bytes: int,
+                        tile_rows: int) -> Tuple[float, float]:
+    """Analytic flops/bytes of one step: ~10 flops per cell; each tile
+    reads a band of ``tile_rows + 2*HALO`` temperature rows and
+    ``tile_rows`` power rows and writes ``tile_rows`` rows, all at the
+    lane-padded width."""
+    rows, cols = shape
+    rows_moved = rows * (2.0 + (tile_rows + 2 * HALO) / tile_rows)
+    return 10.0 * rows * cols, rows_moved * lane_pad(cols) * dtype_bytes
+
+
 def hotspot_step_pallas(temp: jax.Array, power: jax.Array, *,
                         spec: PipelineSpec = PipelineSpec(),
                         tile_rows: int = 8,
@@ -100,16 +125,9 @@ def hotspot_step_pallas(temp: jax.Array, power: jax.Array, *,
     # edge padding: the halo rows replicate the first/last row, which is
     # the clamped boundary; the pad columns are masked in the kernel
     tpad = jnp.pad(temp, ((HALO, HALO), (0, width - cols)), mode="edge")
-    ppad = jnp.pad(power, ((0, 0), (0, width - cols)))
+    ppad = jnp.pad(power.astype(temp.dtype), ((0, 0), (0, width - cols)))
 
-    t_buf, t_sems, t_stage = scratch_for(
-        spec, (tile_rows + 2 * HALO, width), temp.dtype)
-    p_buf, p_sems, p_stage = scratch_for(spec, (tile_rows, width),
-                                         power.dtype)
-    out_buf, out_sems = writeback_scratch(spec, (tile_rows, width),
-                                          temp.dtype)
-    scratch = [t_buf, p_buf, out_buf, t_stage, p_stage,
-               t_sems, p_sems, out_sems]
+    scratch = hotspot_scratch(spec, tile_rows, cols, temp.dtype)
     kernel = functools.partial(
         _hotspot_kernel, spec=spec, n_tiles=n_tiles,
         tile_rows=tile_rows, cols=cols, rx=rx, ry=ry, rz=rz, cap=cap)

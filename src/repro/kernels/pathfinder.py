@@ -9,6 +9,7 @@ benchmark amenable only to the Drop-Off pattern, 1.04-1.11x).
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,31 +45,49 @@ def _pathfinder_kernel(first_hbm, wall_hbm, o_hbm, state, row_buf, stage,
         index=lambda i: (pl.ds(i * tile_rows, tile_rows), slice(None)),
         depth=spec.ring_depth)
 
-    def step(i, r, row):
-        new = row + _min3(state[...], cols)
-        if n_rows % tile_rows:              # the last tile has pad rows
-            new = jnp.where(i * tile_rows + r < n_rows, new, state[...])
-        state[...] = new
+    def fold(i, tile):
+        for r in range(tile_rows):          # static unroll; carried dependency
+            prev = state[...]
+            new = tile[r:r + 1, :] + _min3(prev, cols)
+            if n_rows % tile_rows:          # the last tile has pad rows
+                new = jnp.where(i * tile_rows + r < n_rows, new, prev)
+            state[...] = new
 
     if spec.strategy == Strategy.DROP_OFF:
-        # the tile is held as values: static unroll over its rows
-        def compute_value(i, vals):
-            for r in range(tile_rows):
-                step(i, r, vals[0][r:r + 1, :])
-        emit(spec, [stream], n_tiles, compute_value)
+        emit(spec, [stream], n_tiles, lambda i, vals: fold(i, vals[0]))
     else:
-        # the tile stays in VMEM: one row loaded per loop step, so the
-        # compiled body is one row's worth of code, not tile_rows of it
         def compute(i, bufs):
-            def body(r, _):
-                step(i, r, bufs[0][pl.ds(r, 1), :])
-                return ()
-            jax.lax.fori_loop(0, tile_rows, body, ())
+            fold(i, bufs[0][...])
         emit(spec, [stream], n_tiles, compute, staging=[stage])
 
     out = pltpu.make_async_copy(state, o_hbm, out_sem)
     out.start()
     out.wait()
+
+
+def pathfinder_tiles(rows: int, tile_rows: int) -> int:
+    """Row tiles streamed for a ``rows``-row grid: row 0 seeds the DP state,
+    the other rows are padded up to a whole tile."""
+    return -(-(rows - 1) // tile_rows)
+
+
+def pathfinder_scratch(spec: PipelineSpec, tile_rows: int, cols: int,
+                       dtype) -> list:
+    """The kernel's scratch: the DP state row, the row-tile ring, SYNC
+    staging and the DMA semaphores, all at the lane-padded width."""
+    width = lane_pad(cols)
+    row_buf, sems, stage = scratch_for(spec, (tile_rows, width), dtype)
+    return [pltpu.VMEM((1, width), dtype),      # DP state
+            row_buf, stage, sems, pltpu.SemaphoreType.DMA]
+
+
+def pathfinder_flops_bytes(shape, dtype_bytes: int,
+                           tile_rows: int) -> Tuple[float, float]:
+    """Analytic flops/bytes: ~3 ops per cell; HBM traffic is row 0, the
+    padded row tiles and the result row, all at the lane-padded width."""
+    rows, cols = shape
+    n_rows = 1 + pathfinder_tiles(rows, tile_rows) * tile_rows + 1
+    return 3.0 * rows * cols, float(n_rows * lane_pad(cols) * dtype_bytes)
 
 
 def pathfinder_pallas(wall: jax.Array, *,
@@ -83,14 +102,12 @@ def pathfinder_pallas(wall: jax.Array, *,
     if rows < 2:
         raise ValueError(f"pathfinder needs at least 2 rows, got {rows}")
     n_rows = rows - 1
-    n_tiles = -(-n_rows // tile_rows)
+    n_tiles = pathfinder_tiles(rows, tile_rows)
     width = lane_pad(cols)
     first = jnp.pad(wall[:1], ((0, 0), (0, width - cols)))
     body = jnp.pad(wall[1:], ((0, n_tiles * tile_rows - n_rows),
                               (0, width - cols)))
-    row_buf, sems, stage = scratch_for(spec, (tile_rows, width), wall.dtype)
-    scratch = [pltpu.VMEM((1, width), wall.dtype),      # DP state
-               row_buf, stage, sems, pltpu.SemaphoreType.DMA]
+    scratch = pathfinder_scratch(spec, tile_rows, cols, wall.dtype)
     kernel = functools.partial(
         _pathfinder_kernel, spec=spec, n_tiles=n_tiles, tile_rows=tile_rows,
         n_rows=n_rows, cols=cols)

@@ -73,6 +73,9 @@ class Request:
     ttft_ms: Optional[float] = None     # arrival -> first token (incl.
     #                                     queue wait)
     total_ms: Optional[float] = None    # arrival -> request finished
+    #: a list to opt in: ``ContinuousScheduler`` then appends the logits
+    #: row (a device array) each output token was chosen from
+    logits: Optional[list] = None
 
 
 def sample(logits, key, temperature: float = 0.0):
@@ -116,6 +119,13 @@ def build_serve_fns(model, rules=None, budget=None):
             return model.decode_step(params, state, tokens)
 
     return jax.jit(prefill), jax.jit(decode_step, donate_argnums=(1,))
+
+
+def _keep_logits(req: Request, logits, i: int) -> None:
+    """Record row ``i`` of ``logits`` if ``req`` asked for its logits (the
+    slice is taken only then, so the serving path pays nothing)."""
+    if req.logits is not None:
+        req.logits.append(logits[i])
 
 
 def _request_key(base_key, uid: int):
@@ -375,6 +385,7 @@ class ContinuousScheduler(_SchedulerBase):
                 r.ttft_ms = (t_first - arrive_wall[r.uid]) * 1e3
                 ttft_h.observe(r.ttft_ms)
                 r.out_tokens.append(tok)
+                _keep_logits(r, logits, 0)
                 slots[i] = _Slot(r, pos=plen, target=target,
                                  t_arrive=arrive_wall[r.uid])
                 if len(r.out_tokens) >= target:
@@ -431,6 +442,7 @@ class ContinuousScheduler(_SchedulerBase):
                 s = slots[i]
                 s.pos += 1
                 s.req.out_tokens.append(int(toks[i]))
+                _keep_logits(s.req, logits, i)
                 if len(s.req.out_tokens) >= s.target:
                     finish(i)
         qdepth.set(0)
@@ -575,6 +587,7 @@ class ContinuousScheduler(_SchedulerBase):
                                  - arrive_wall[r.uid]) * 1e3
                     ttft_h.observe(r.ttft_ms)
                     r.out_tokens.append(tok)
+                    _keep_logits(r, logits, 0)
                     s.prefilling = False
                     if len(r.out_tokens) >= s.target:
                         finish(i)
@@ -636,6 +649,7 @@ class ContinuousScheduler(_SchedulerBase):
                 s = slots[i]
                 s.pos += 1
                 s.req.out_tokens.append(int(toks[i]))
+                _keep_logits(s.req, logits, i)
                 if len(s.req.out_tokens) >= s.target:
                     finish(i)
         qdepth.set(0)

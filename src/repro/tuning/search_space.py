@@ -22,9 +22,13 @@ import jax
 import jax.numpy as jnp
 
 from ..core import hardware
-from ..core.async_pipeline import Strategy
+from ..core.async_pipeline import (PipelineSpec, Strategy, lane_pad,
+                                   vmem_bytes)
 from ..kernels import ops
+from ..kernels.hotspot import hotspot_flops_bytes, hotspot_scratch
 from ..kernels.matmul import matmul_vmem_bytes
+from ..kernels.pathfinder import (pathfinder_flops_bytes, pathfinder_scratch,
+                                  pathfinder_tiles)
 from ..kernels.stream import stream_flops_bytes
 
 #: keep candidates predicted within this factor of the analytic best
@@ -176,6 +180,11 @@ def _strategy_depth_pairs():
             for d, w in strategy_depth_waits(s)]
 
 
+#: row-tile heights for the row-streaming kernels: whole multiples of the
+#: 8-row sublane tile, the smallest a 32-bit DMA or slice may be cut at
+SUBLANE_TILE_ROWS: Tuple[int, ...] = (8, 16, 32)
+
+
 def _dtype_bytes(dtype) -> int:
     return jnp.dtype(dtype).itemsize
 
@@ -287,7 +296,7 @@ def _hotspot_configs(shape):
     rows, _ = shape
     out = []
     for (s, depth, wg), tr in itertools.product(_strategy_depth_pairs(),
-                                                (8, 16, 32)):
+                                                SUBLANE_TILE_ROWS):
         if rows % tr:
             continue
         out.append(dict(strategy=s, depth=depth, wait_group=wg,
@@ -296,14 +305,8 @@ def _hotspot_configs(shape):
 
 
 def _hotspot_vmem(shape, dtype, cfg):
-    _, cols = shape
-    isz = _dtype_bytes(dtype)
-    t_tile = (cfg["tile_rows"] + 2) * (cols + 2) * isz
-    p_tile = cfg["tile_rows"] * cols * isz
-    d = 1 if cfg["strategy"] in (Strategy.SYNC, Strategy.REGISTER_BYPASS) \
-        else cfg["depth"]
-    stage = (t_tile + p_tile) if cfg["strategy"] == Strategy.SYNC else 0
-    return d * (t_tile + p_tile) + cfg.get("out_depth", 2) * p_tile + stage
+    return vmem_bytes(hotspot_scratch(PipelineSpec.from_config(cfg),
+                                      cfg["tile_rows"], shape[1], dtype))
 
 
 HOTSPOT = KernelSpec(
@@ -314,9 +317,8 @@ HOTSPOT = KernelSpec(
     call=lambda args, cfg, interp: ops.hotspot(
         args[0], args[1], iters=1, interpret=interp, **cfg),
     enumerate_configs=_hotspot_configs,
-    flops_bytes=lambda shape, dtype, cfg: (
-        10.0 * shape[0] * shape[1],
-        3.0 * shape[0] * shape[1] * _dtype_bytes(dtype)),
+    flops_bytes=lambda shape, dtype, cfg: hotspot_flops_bytes(
+        shape, _dtype_bytes(dtype), cfg["tile_rows"]),
     n_tiles=lambda shape, cfg: max(shape[0] // cfg["tile_rows"], 1),
     vmem_bytes=_hotspot_vmem,
 )
@@ -362,7 +364,7 @@ def _nw_configs(shape):
     n = shape[0]
     out = []
     for (s, depth, wg), tr in itertools.product(_strategy_depth_pairs(),
-                                                (4, 8, 16)):
+                                                SUBLANE_TILE_ROWS):
         if n % tr:
             continue
         out.append(dict(strategy=s, depth=depth, wait_group=wg,
@@ -398,15 +400,10 @@ NW = KernelSpec(
 # -- pathfinder -------------------------------------------------------------
 
 def _pathfinder_configs(shape):
-    rows, _ = shape
-    out = []
-    for (s, depth, wg), tr in itertools.product(_strategy_depth_pairs(),
-                                                (4, 8, 16)):
-        if (rows - 1) % tr:
-            continue
-        out.append(dict(strategy=s, depth=depth, wait_group=wg,
-                        tile_rows=tr))
-    return out
+    # any row count: the wrapper pads rows to a whole tile
+    return [dict(strategy=s, depth=depth, wait_group=wg, tile_rows=tr)
+            for (s, depth, wg), tr in itertools.product(
+                _strategy_depth_pairs(), SUBLANE_TILE_ROWS)]
 
 
 PATHFINDER = KernelSpec(
@@ -417,14 +414,12 @@ PATHFINDER = KernelSpec(
     call=lambda args, cfg, interp: ops.pathfinder(
         args[0], interpret=interp, **cfg),
     enumerate_configs=_pathfinder_configs,
-    flops_bytes=lambda shape, dtype, cfg: (
-        3.0 * shape[0] * shape[1], float(shape[0] * shape[1] * 4)),
-    n_tiles=lambda shape, cfg: max((shape[0] - 1) // cfg["tile_rows"], 1),
-    vmem_bytes=lambda shape, dtype, cfg: (
-        ((1 if cfg["strategy"] in (Strategy.SYNC, Strategy.REGISTER_BYPASS)
-          else cfg["depth"]) + 2 +
-         (1 if cfg["strategy"] == Strategy.SYNC else 0))
-        * cfg["tile_rows"] * shape[1] * 4),
+    flops_bytes=lambda shape, dtype, cfg: pathfinder_flops_bytes(
+        shape, 4, cfg["tile_rows"]),
+    n_tiles=lambda shape, cfg: pathfinder_tiles(shape[0], cfg["tile_rows"]),
+    vmem_bytes=lambda shape, dtype, cfg: vmem_bytes(pathfinder_scratch(
+        PipelineSpec.from_config(cfg), cfg["tile_rows"], shape[1],
+        jnp.int32)),
 )
 
 
@@ -464,9 +459,10 @@ FLASH = KernelSpec(
     n_tiles=lambda shape, cfg: max(shape[1] // cfg["bk"], 1),
     vmem_bytes=lambda shape, dtype, cfg: (
         ((1 if cfg["strategy"] in (Strategy.SYNC, Strategy.REGISTER_BYPASS)
-          else cfg["depth"]) * 2 * cfg["bk"] * shape[2]
+          else cfg["depth"]) * 2 * cfg["bk"] * lane_pad(shape[2])
          * _dtype_bytes(dtype))
-        + cfg["bq"] * shape[2] * (_dtype_bytes(dtype) + 4) + cfg["bq"] * 8),
+        + cfg["bq"] * lane_pad(shape[2]) * (_dtype_bytes(dtype) + 4)
+        + cfg["bq"] * 8),
 )
 
 

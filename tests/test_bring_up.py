@@ -16,6 +16,7 @@ from repro.configs import get_config
 from repro.core import Strategy, compile_cache, hardware
 from repro.kernels import lud, ops, ref
 from repro.launch import train
+from repro.tuning.search_space import SearchSpace
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 
@@ -119,3 +120,39 @@ def test_lud_pads_to_lane_multiple():
     got = np.asarray(ops.lud(a, bs=32, strategy=Strategy.SYNC))
     assert got.shape == (n, n)
     np.testing.assert_allclose(got, ref.lud_ref(a), rtol=2e-4, atol=2e-4)
+
+
+# --- the tuner models the kernels that run -----------------------------------
+
+@pytest.mark.parametrize("kernel,shape", [("hotspot", (1024, 1024)),
+                                          ("nw", (2048,)),
+                                          ("pathfinder", (100, 100000))])
+def test_tuner_row_tiles_are_sublane_aligned(kernel, shape):
+    # Mosaic refuses a 32-bit row slice that is not a multiple of 8 rows
+    tiles = {c.config["tile_rows"]
+             for c in SearchSpace(kernel, shape).candidates()}
+    assert tiles and all(t % 8 == 0 for t in tiles)
+
+
+def test_tuner_vmem_counts_hotspot_halo_band():
+    # 1000 columns pad to 1024 lanes; a tile_rows=8 band holds 8 + 16 rows
+    cfg = dict(strategy=Strategy.OVERLAP, depth=2, wait_group=None,
+               out_depth=2, tile_rows=8)
+    c = SearchSpace("hotspot", (1024, 1000)).annotate(cfg)
+    rings = 2 * (8 + 16) * 1024 * 4 + 2 * 8 * 1024 * 4 + 2 * 8 * 1024 * 4
+    assert rings <= c.vmem_bytes < rings + 64      # + 1-element stage stubs
+
+
+def test_tuner_pathfinder_candidates_run_at_any_row_count():
+    # rows - 1 = 20 divides none of the tile heights; the wrapper pads
+    space = SearchSpace("pathfinder", (21, 200))
+    cands = space.candidates()
+    assert {c.config["tile_rows"] for c in cands} == {8, 16, 32}
+    assert all(space.spec.n_tiles(space.shape, c.config)
+               == -(-20 // c.config["tile_rows"]) for c in cands)
+    wall = jax.random.randint(jax.random.PRNGKey(14), (21, 200), 0, 10,
+                              jnp.int32)
+    cfg = next(c.config for c in cands if c.config["tile_rows"] == 16)
+    got = ops.pathfinder(wall, **cfg)
+    np.testing.assert_array_equal(np.asarray(got)[0],
+                                  ref.pathfinder_ref(wall))

@@ -593,6 +593,24 @@ def test_chunked_matches_chunked_solo_oracle_two_orders(served):
         assert rev == oracle
 
 
+@pytest.mark.parametrize("chunk", [None, 16])
+def test_requested_logits_are_the_rows_tokens_came_from(served, chunk):
+    """A request that asks for its logits gets one row per output token,
+    each the row its greedy token is the argmax of; one that does not ask
+    records nothing."""
+    cfg, _, params = served
+    reqs = _reqs(cfg, lens=(21, 7, 12), max_new=(3, 4, 3), seed=13)
+    for r in reqs[:2]:
+        r.logits = []
+    ContinuousScheduler(cfg, params, batch=2, max_seq=64, block_len=8,
+                        chunk_tokens=chunk).run(reqs)
+    for r in reqs[:2]:
+        assert len(r.logits) == len(r.out_tokens) == r.max_new
+        assert [int(jnp.argmax(row)) for row in r.logits] == r.out_tokens
+        assert all(row.shape == (cfg.vocab,) for row in r.logits)
+    assert reqs[2].logits is None
+
+
 def test_prefix_sharing_hits_and_is_bit_identical(served):
     """Requests sharing a 32-token prefix: the prefix cache must serve
     later prefills from shared blocks (hit_tokens > 0) without changing
