@@ -6,7 +6,10 @@ becomes inspectable events:
 
   trace      nested context-manager spans on the monotonic clock, a
              thread-safe buffer, JSONL sink, and Chrome-trace/Perfetto
-             export; OFF by default (one attribute check on the hot path)
+             export; recorded when enabled or while a JAX profiler
+             session is active, where each span is also a
+             ``TraceAnnotation`` on the profiler's clock; off otherwise
+             (an attribute check and a profiler query, no allocation)
   metrics    labeled counters / gauges / histograms with quantile
              snapshots (the serving loop's TTFT & per-token latencies)
   compare    noise-aware BENCH_*.json regression gate — median +/- k*IQR

@@ -12,13 +12,25 @@ exports it two ways:
                  https://ui.perfetto.dev load directly (complete "X"
                  events; span nesting becomes track stacking)
 
-Tracing is OFF by default and the disabled path is a single attribute
-check — the canonical timer's hot loop must not move by even a
-microsecond when nobody is tracing.  Producers therefore either use
-``tracer.span(...)`` as a context manager (fine outside timed regions) or
-``tracer.record(name, t0, t1, ...)`` to log an interval *retroactively*
-from timestamps they already took (``bench.timing`` does this: the timed
-region contains zero tracing code).
+The tracer follows the JAX profiler.  A live span (``tracer.span(...)``)
+is recorded when the tracer is enabled, or while a JAX profiler session
+is active (``jax.profiler.TraceAnnotation.is_enabled()``), and it is kept
+in the buffer only if recording was on both when it opened and when it
+closed: a span open across ``start_trace`` or ``stop_trace`` would carry
+the profiler's own start-up or write-out time, so it is dropped.  While a
+session is active a span also enters a ``jax.profiler.TraceAnnotation``
+under its bare name, which puts it on the profiler's host clock in the
+same trace file as the device's operations; its attributes stay on the
+in-memory ``Span`` (the annotation would fold them into the event name).
+
+Recording is off by default.  The off path returns one shared no-op
+context manager after an attribute check and one question to the
+profiler (about a tenth of a microsecond), so the canonical timer's hot
+loop does not move when nobody is tracing.  Producers either use ``tracer.span(...)`` as a context
+manager (fine outside timed regions) or ``tracer.record(name, t0, t1,
+...)`` to log an interval *retroactively* from timestamps they already
+took (``bench.timing`` does this: the timed region contains zero tracing
+code); ``record`` is gated on ``enabled`` alone.
 
 Nesting is tracked per thread: a span opened while another is open on the
 same thread becomes its child, and ``record()`` attaches to the innermost
@@ -80,8 +92,21 @@ def _now_us() -> float:
     return time.perf_counter() * 1e6
 
 
+_ANNOTATION = None
+
+
+def _profiler_session():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is active,
+    else None.  JAX is imported on the first call, not with this module."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION if _ANNOTATION.is_enabled() else None
+
+
 class _NoopSpanCtx:
-    """The disabled path: one shared immutable context manager."""
+    """The off path: one shared immutable context manager."""
     __slots__ = ()
 
     def __enter__(self):
@@ -95,13 +120,16 @@ _NOOP = _NoopSpanCtx()
 
 
 class _SpanCtx:
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, annotation):
         self._tracer = tracer
         self._span = span
+        self._annotation = annotation
 
     def __enter__(self) -> Span:
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._tracer._push(self._span)
         return self._span
 
@@ -109,6 +137,8 @@ class _SpanCtx:
         if exc_type is not None:
             self._span.attrs.setdefault("error", exc_type.__name__)
         self._tracer._pop(self._span)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -152,15 +182,17 @@ class Tracer:
 
     def span(self, name: str, **attrs: Any):
         """Context manager for a live span; yields the ``Span`` (or None
-        when tracing is disabled, so ``with ... as sp: if sp:`` guards)."""
-        if not self.enabled:
+        when nothing records, so ``with ... as sp: if sp:`` guards)."""
+        annotation = _profiler_session()
+        if annotation is None and not self.enabled:
             return _NOOP
         parent = self.current()
         sp = Span(name=name, t0_us=_now_us(), attrs=attrs,
                   parent_id=parent.span_id if parent else None,
                   trace_id=self.trace_id,
                   thread_id=threading.get_ident() & 0x7FFFFFFF)
-        return _SpanCtx(self, sp)
+        return _SpanCtx(self, sp,
+                        annotation(name) if annotation else None)
 
     def _push(self, sp: Span) -> None:
         self._stack().append(sp)
@@ -170,8 +202,9 @@ class Tracer:
         st = self._stack()
         if st and st[-1] is sp:
             st.pop()
-        with self._lock:
-            self._spans.append(sp)
+        if self.enabled or _profiler_session() is not None:  # still on
+            with self._lock:
+                self._spans.append(sp)
 
     def record(self, name: str, t0_s: float, t1_s: float,
                **attrs: Any) -> Optional[Span]:

@@ -35,9 +35,28 @@ loop did:
   serve.decode_ms         histogram, per decode step (per-token latency)
   serve.batch_occupancy   histogram, active/batch per decode step
                           (per cohort prefill for CohortScheduler)
-  serve.queue_depth       gauge, arrived requests not yet in a slot
   serve.requests_total    counter
   serve.tokens_total      counter
+
+``ContinuousScheduler`` also records one span tree per scheduler
+iteration on the process's tracer (``repro.obs.trace``), which records
+while it is enabled or while a JAX profiler session is active.  One span
+per phase, never one per slot, each a child of the iteration's:
+
+  serve.step              the iteration, from the arrival drain to the end
+                          of readback; ``active``, ``queued``,
+                          ``prefilling`` as counted after the drain
+  serve.admit             one admission (arena blocks, prefix match);
+                          ``uid``, ``prompt_len``, ``matched``, ``waited_ms``
+  serve.prefill           one monolithic prefill dispatch, or
+  serve.prefill_chunk     one chunk dispatch; ``uid``, ``start``, ``n_tokens``
+  serve.decode_step       the decode dispatch (inputs, block-table upload,
+                          the jitted call); ``active``
+  serve.device_wait       each wait for the device's tokens; ``on`` is
+                          ``prefill``, ``chunk`` or ``decode``
+  serve.sample            sampling at temperature > 0; ``active``
+  serve.readback          tokens to the host, appends, finished requests
+                          freed; ``n_tokens``, ``finished``
 """
 from __future__ import annotations
 
@@ -128,6 +147,17 @@ def _keep_logits(req: Request, logits, i: int) -> None:
         req.logits.append(logits[i])
 
 
+def _since_ms(t: float) -> float:
+    return (time.perf_counter() - t) * 1e3
+
+
+def _occupancy(slots) -> Dict[str, int]:
+    """Decoding and mid-prefill slots, as ``serve.step`` records them."""
+    prefilling = sum(s is not None and s.prefilling for s in slots)
+    return {"active": sum(s is not None for s in slots) - prefilling,
+            "prefilling": prefilling}
+
+
 def _request_key(base_key, uid: int):
     """Per-request PRNG stream: independent of scheduling order, so
     sampled outputs don't change when the batch composition does."""
@@ -155,7 +185,6 @@ class _SchedulerBase:
         m = self.metrics
         return (m.histogram("serve.ttft_ms"), m.histogram("serve.decode_ms"),
                 m.histogram("serve.batch_occupancy"),
-                m.gauge("serve.queue_depth"),
                 m.counter("serve.requests_total"),
                 m.counter("serve.tokens_total"))
 
@@ -314,12 +343,79 @@ class ContinuousScheduler(_SchedulerBase):
 
     # -- main loop ----------------------------------------------------------
 
+    def _first_token(self, on: str, logits, greedy, uid: int,
+                     temperature: float, base_key):
+        """Wait for a prefill's first token; returns the (1,) device array
+        that holds it."""
+        tracer = get_tracer()
+        if temperature > 0:
+            with tracer.span("serve.sample", active=1):
+                greedy = sample(logits, jax.random.fold_in(
+                    _request_key(base_key, uid), 0), temperature)
+        with tracer.span("serve.device_wait", on=on):
+            return jax.block_until_ready(greedy)
+
+    def _decode_and_read(self, slots, active, temperature, base_key,
+                         finish, dec_h, occ_h) -> None:
+        """One decode step over the ``active`` slots, then their tokens to
+        the host.  Mid-prefill slots are masked out of the block table:
+        their decode rows are inactive (slot_pos 0) and must write the
+        scratch block, not the real block their table maps at row 0."""
+        tracer = get_tracer()
+        occ_h.observe(len(active) / self.batch)
+        with tracer.span("serve.decode_step", active=len(active)):
+            tokens = np.zeros((self.batch, 1), np.int32)
+            slot_pos = np.zeros((self.batch,), np.int32)
+            for i in active:
+                s = slots[i]
+                tokens[i, 0] = s.req.out_tokens[-1]
+                slot_pos[i] = s.pos
+                self.cache.append(i, s.pos)
+            t0 = time.perf_counter()
+            pref = [i for i, s in enumerate(slots)
+                    if s is not None and s.prefilling]
+            if pref:
+                tbl = self.cache.tables.copy()
+                tbl[pref] = -1
+                tables = jnp.asarray(tbl)
+            else:
+                tables = self.cache.device_tables()
+            logits, greedy, self.cache.state = self._decode(
+                self.params, self.cache.state, jnp.asarray(tokens),
+                tables, jnp.asarray(slot_pos))
+        with tracer.span("serve.device_wait", on="decode"):
+            toks = jax.block_until_ready(greedy if temperature <= 0
+                                         else logits)
+        if temperature > 0:
+            with tracer.span("serve.sample", active=len(active)):
+                toks = np.zeros((self.batch,), np.int64)
+                for i in active:
+                    s = slots[i]
+                    step_key = jax.random.fold_in(
+                        _request_key(base_key, s.req.uid),
+                        len(s.req.out_tokens))
+                    toks[i] = int(jax.block_until_ready(sample(
+                        logits[i:i + 1], step_key, temperature))[0])
+        dec_h.observe((time.perf_counter() - t0) * 1e3)
+        with tracer.span("serve.readback", n_tokens=len(active)) as rb:
+            finished = 0
+            for i in active:
+                s = slots[i]
+                s.pos += 1
+                s.req.out_tokens.append(int(toks[i]))
+                _keep_logits(s.req, logits, i)
+                if len(s.req.out_tokens) >= s.target:
+                    finish(i)
+                    finished += 1
+            if rb is not None:
+                rb.attrs["finished"] = finished
+
     def run(self, requests: List[Request], temperature: float = 0.0,
             max_steps: int = 64) -> Dict[int, List[int]]:
         if self.chunk_tokens is not None:
             return self._run_chunked(requests, temperature, max_steps)
         tracer = get_tracer()
-        ttft_h, dec_h, occ_h, qdepth, req_c, tok_c = self._metric_handles()
+        ttft_h, dec_h, occ_h, req_c, tok_c = self._metric_handles()
         base_key = jax.random.PRNGKey(self.seed)
 
         pending = deque(sorted(requests, key=lambda r: (r.arrival, r.uid)))
@@ -339,113 +435,84 @@ class ContinuousScheduler(_SchedulerBase):
             self.cache.free_slot(i)
             slots[i] = None
 
-        while pending or queue or any(s is not None for s in slots):
-            # arrivals: pending -> queue once the virtual clock reaches them
+        def drain_arrivals():
+            # pending -> queue once the virtual clock reaches them
             now = time.perf_counter()
             while pending and pending[0].arrival <= clock:
                 r = pending.popleft()
                 queue.append(r)
                 arrive_wall[r.uid] = now
-            qdepth.set(len(queue))
 
-            # admission: refill free slots while the arena has room
-            n_pref = 0
-            while queue and n_pref < self.max_prefills_per_step:
-                free = [i for i, s in enumerate(slots) if s is None]
-                if not free:
+        while pending or queue or any(s is not None for s in slots):
+            with tracer.span("serve.step") as step:
+                drain_arrivals()
+                if step is not None:
+                    step.attrs.update(_occupancy(slots), queued=len(queue))
+
+                # admission: refill free slots while the arena has room
+                n_pref = 0
+                while queue and n_pref < self.max_prefills_per_step:
+                    free = [i for i, s in enumerate(slots) if s is None]
+                    if not free:
+                        break
+                    r = queue[0]
+                    target = min(r.max_new, max_steps)
+                    plen = len(r.prompt) + self._extra_rows
+                    bucket = self._bucket(plen)
+                    lifetime = max(bucket, plen + target)
+                    if not self.cache.can_admit(lifetime):
+                        if not any(s is not None for s in slots):
+                            raise RuntimeError(
+                                f"request {r.uid} (lifetime {lifetime} "
+                                f"tokens) cannot fit the arena even when "
+                                f"idle")
+                        break           # wait for a slot to free blocks
+                    queue.popleft()
+                    i = free[0]
+                    with tracer.span("serve.admit", uid=r.uid,
+                                     prompt_len=len(r.prompt), matched=0,
+                                     waited_ms=_since_ms(arrive_wall[r.uid])):
+                        ids = self.cache.admit(i, bucket, lifetime)
+                    with tracer.span("serve.prefill", uid=r.uid, start=0,
+                                     n_tokens=len(r.prompt)):
+                        logits, greedy, self.cache.state = self._get_prefill(
+                            bucket)(self.params, self._prefill_batch(r.prompt),
+                                    self.cache.state,
+                                    jnp.asarray(ids, jnp.int32))
+                    first = self._first_token("prefill", logits, greedy,
+                                              r.uid, temperature, base_key)
+                    with tracer.span("serve.readback", n_tokens=1) as rb:
+                        tok = int(first[0])
+                        r.ttft_ms = _since_ms(arrive_wall[r.uid])
+                        ttft_h.observe(r.ttft_ms)
+                        r.out_tokens.append(tok)
+                        _keep_logits(r, logits, 0)
+                        slots[i] = _Slot(r, pos=plen, target=target,
+                                         t_arrive=arrive_wall[r.uid])
+                        done = len(r.out_tokens) >= target
+                        if done:
+                            finish(i)
+                        if rb is not None:
+                            rb.attrs["finished"] = int(done)
+                    n_pref += 1
+                    clock += 1.0
+                    drain_arrivals()
+
+                active = [i for i, s in enumerate(slots) if s is not None]
+                if not active:
+                    if pending:
+                        # idle: jump the virtual clock to the next arrival
+                        clock = max(clock, pending[0].arrival)
+                        continue
+                    if queue:
+                        continue        # admission will retry (or raise)
                     break
-                r = queue[0]
-                target = min(r.max_new, max_steps)
-                plen = len(r.prompt) + self._extra_rows
-                bucket = self._bucket(plen)
-                lifetime = max(bucket, plen + target)
-                if not self.cache.can_admit(lifetime):
-                    if not any(s is not None for s in slots):
-                        raise RuntimeError(
-                            f"request {r.uid} (lifetime {lifetime} tokens) "
-                            f"cannot fit the arena even when idle")
-                    break               # wait for a slot to free blocks
-                queue.popleft()
-                i = free[0]
-                with tracer.span("serve.prefill", uid=r.uid,
-                                 prompt_len=len(r.prompt), bucket=bucket):
-                    ids = self.cache.admit(i, bucket, lifetime)
-                    logits, greedy, self.cache.state = self._get_prefill(
-                        bucket)(self.params, self._prefill_batch(r.prompt),
-                                self.cache.state,
-                                jnp.asarray(ids, jnp.int32))
-                    if temperature <= 0:
-                        tok = int(jax.block_until_ready(greedy)[0])
-                    else:
-                        key = _request_key(base_key, r.uid)
-                        tok = int(jax.block_until_ready(
-                            sample(logits, jax.random.fold_in(key, 0),
-                                   temperature))[0])
-                t_first = time.perf_counter()
-                r.ttft_ms = (t_first - arrive_wall[r.uid]) * 1e3
-                ttft_h.observe(r.ttft_ms)
-                r.out_tokens.append(tok)
-                _keep_logits(r, logits, 0)
-                slots[i] = _Slot(r, pos=plen, target=target,
-                                 t_arrive=arrive_wall[r.uid])
-                if len(r.out_tokens) >= target:
-                    finish(i)
-                n_pref += 1
+
+                # one decode step over every slot (inactive slots write the
+                # scratch block and their logits are discarded)
+                self._decode_and_read(slots, active, temperature, base_key,
+                                      finish, dec_h, occ_h)
                 clock += 1.0
-                now = time.perf_counter()
-                while pending and pending[0].arrival <= clock:
-                    rr = pending.popleft()
-                    queue.append(rr)
-                    arrive_wall[rr.uid] = now
-                qdepth.set(len(queue))
-
-            active = [i for i, s in enumerate(slots) if s is not None]
-            if not active:
-                if pending:
-                    # idle: jump the virtual clock to the next arrival
-                    clock = max(clock, pending[0].arrival)
-                    continue
-                if queue:
-                    continue            # admission will retry (or raise)
-                break
-
-            # one decode step over every slot (inactive slots write the
-            # scratch block and their logits are discarded)
-            occ_h.observe(len(active) / self.batch)
-            tokens = np.zeros((self.batch, 1), np.int32)
-            slot_pos = np.zeros((self.batch,), np.int32)
-            for i in active:
-                s = slots[i]
-                tokens[i, 0] = s.req.out_tokens[-1]
-                slot_pos[i] = s.pos
-                self.cache.append(i, s.pos)
-            t0 = time.perf_counter()
-            with tracer.span("serve.decode_step", n_active=len(active),
-                             queued=len(queue)):
-                logits, greedy, self.cache.state = self._decode(
-                    self.params, self.cache.state, jnp.asarray(tokens),
-                    self.cache.device_tables(), jnp.asarray(slot_pos))
-                if temperature <= 0:
-                    toks = jax.block_until_ready(greedy)
-                else:
-                    toks = np.zeros((self.batch,), np.int64)
-                    for i in active:
-                        s = slots[i]
-                        key = _request_key(base_key, s.req.uid)
-                        step_key = jax.random.fold_in(
-                            key, len(s.req.out_tokens))
-                        toks[i] = int(jax.block_until_ready(sample(
-                            logits[i:i + 1], step_key, temperature))[0])
-            dec_h.observe((time.perf_counter() - t0) * 1e3)
-            clock += 1.0
-            for i in active:
-                s = slots[i]
-                s.pos += 1
-                s.req.out_tokens.append(int(toks[i]))
-                _keep_logits(s.req, logits, i)
-                if len(s.req.out_tokens) >= s.target:
-                    finish(i)
-        qdepth.set(0)
         return results
 
     def _run_chunked(self, requests: List[Request], temperature: float,
@@ -458,7 +525,7 @@ class ContinuousScheduler(_SchedulerBase):
         inactive-row scratch write can never land in their (possibly
         shared) blocks."""
         tracer = get_tracer()
-        ttft_h, dec_h, occ_h, qdepth, req_c, tok_c = self._metric_handles()
+        ttft_h, dec_h, occ_h, req_c, tok_c = self._metric_handles()
         hit_c = self.metrics.counter("serve.prefix_hit_tokens")
         miss_c = self.metrics.counter("serve.prefix_miss_tokens")
         base_key = jax.random.PRNGKey(self.seed)
@@ -487,172 +554,138 @@ class ContinuousScheduler(_SchedulerBase):
                 r = pending.popleft()
                 queue.append(r)
                 arrive_wall[r.uid] = now
-            qdepth.set(len(queue))
 
         while pending or queue or any(s is not None for s in slots):
-            drain_arrivals()
-
-            # admission: reserve blocks + map shared prefix, no dispatch.
-            # The match is capped to whole chunks strictly below the
-            # prompt's last row, so at least one chunk (and the first
-            # token's logits) is always computed live with the same
-            # dispatch shape the solo oracle uses.
-            n_adm = 0
-            while queue and n_adm < self.max_prefills_per_step:
-                free = [i for i, s in enumerate(slots) if s is None]
-                if not free:
-                    break
-                r = queue[0]
-                target = min(r.max_new, max_steps)
-                plen = len(r.prompt)
-                lifetime = plen + target
-                if not self.cache.can_admit(lifetime):
-                    if not any(s is not None for s in slots):
-                        raise RuntimeError(
-                            f"request {r.uid} (lifetime {lifetime} tokens)"
-                            f" cannot fit the arena even when idle")
-                    break               # wait for a slot to free blocks
-                queue.popleft()
-                i = free[0]
-                matched = self.cache.admit_shared(
-                    i, np.asarray(r.prompt, np.int32).reshape(-1),
-                    lifetime, max_match_rows=((plen - 1) // T) * T,
-                    granule_rows=T)
-                hit_c.inc(matched)
-                miss_c.inc(plen - matched)
-                slots[i] = _Slot(r, pos=plen, target=target,
-                                 t_arrive=arrive_wall[r.uid], plen=plen,
-                                 filled=matched, prefilling=True)
-                n_adm += 1
-
-            # one prefill chunk for the oldest mid-prefill slot
-            pref = [i for i, s in enumerate(slots)
-                    if s is not None and s.prefilling]
-            if pref:
-                i = min(pref, key=lambda j: (slots[j].req.arrival,
-                                             slots[j].req.uid))
-                s = slots[i]
-                r = s.req
-                if not s.started:
-                    # last chance to share: a producer that was still
-                    # mid-prefill at our admission has registered its
-                    # completed chunks by now — graft them on while this
-                    # slot has written nothing
-                    grown = self.cache.extend_match(
-                        i, np.asarray(r.prompt, np.int32).reshape(-1),
-                        max_match_rows=((s.plen - 1) // T) * T,
-                        granule_rows=T)
-                    if grown > s.filled:
-                        hit_c.inc(grown - s.filled)
-                        miss_c.inc(s.filled - grown)
-                        s.filled = grown
-                    s.started = True
-                start = s.filled
-                n = min(T, s.plen - start)
-                width = next_pow2(n)
-                self.cache.extend_to(i, start + n)
-                toks = np.zeros((1, width), np.int32)
-                toks[0, :n] = np.asarray(r.prompt,
-                                         np.int32).reshape(-1)[start:start + n]
-                with tracer.span("serve.prefill_chunk", uid=r.uid,
-                                 start=start, n_tokens=n):
-                    logits, greedy, self.cache.state = self._get_chunk(
-                        width)(self.params, self.cache.state,
-                               jnp.asarray(toks),
-                               jnp.asarray(self.cache.tables[i:i + 1]),
-                               jnp.int32(start), jnp.int32(n))
-                    s.filled = start + n
-                    last = s.filled >= s.plen
-                    if last:
-                        if temperature <= 0:
-                            tok = int(jax.block_until_ready(greedy)[0])
-                        else:
-                            key = _request_key(base_key, r.uid)
-                            tok = int(jax.block_until_ready(
-                                sample(logits, jax.random.fold_in(key, 0),
-                                       temperature))[0])
-                clock += 1.0
-                # register incrementally: rows in completed absolute
-                # chunks are final (later chunks never rewrite them), so
-                # a prompt arriving mid-prefill can already share them.
-                # Only FULL aligned chunks qualify — rows of a final
-                # partial chunk ran at a different dispatch width, so
-                # their low bits are not what a sharing consumer's
-                # oracle would produce.
-                self.cache.register_prefix(
-                    i, np.asarray(r.prompt, np.int32).reshape(-1),
-                    (s.filled // T) * T)
-                if last:
-                    r.ttft_ms = (time.perf_counter()
-                                 - arrive_wall[r.uid]) * 1e3
-                    ttft_h.observe(r.ttft_ms)
-                    r.out_tokens.append(tok)
-                    _keep_logits(r, logits, 0)
-                    s.prefilling = False
-                    if len(r.out_tokens) >= s.target:
-                        finish(i)
+            with tracer.span("serve.step") as step:
                 drain_arrivals()
+                if step is not None:
+                    step.attrs.update(_occupancy(slots), queued=len(queue))
 
-            active = [i for i, s in enumerate(slots)
-                      if s is not None and not s.prefilling]
-            if not active:
-                if any(s is not None for s in slots):
-                    continue            # prefill chunks still in flight
-                if pending:
-                    # idle: jump the virtual clock to the next arrival
-                    clock = max(clock, pending[0].arrival)
-                    continue
-                if queue:
-                    continue            # admission will retry (or raise)
-                break
+                # admission: reserve blocks + map shared prefix, no
+                # dispatch.  The match is capped to whole chunks strictly
+                # below the prompt's last row, so at least one chunk (and
+                # the first token's logits) is always computed live with
+                # the same dispatch shape the solo oracle uses.
+                n_adm = 0
+                while queue and n_adm < self.max_prefills_per_step:
+                    free = [i for i, s in enumerate(slots) if s is None]
+                    if not free:
+                        break
+                    r = queue[0]
+                    target = min(r.max_new, max_steps)
+                    plen = len(r.prompt)
+                    lifetime = plen + target
+                    if not self.cache.can_admit(lifetime):
+                        if not any(s is not None for s in slots):
+                            raise RuntimeError(
+                                f"request {r.uid} (lifetime {lifetime} "
+                                f"tokens) cannot fit the arena even when "
+                                f"idle")
+                        break           # wait for a slot to free blocks
+                    queue.popleft()
+                    i = free[0]
+                    with tracer.span("serve.admit", uid=r.uid,
+                                     prompt_len=plen,
+                                     waited_ms=_since_ms(arrive_wall[r.uid])
+                                     ) as adm:
+                        matched = self.cache.admit_shared(
+                            i, np.asarray(r.prompt, np.int32).reshape(-1),
+                            lifetime, max_match_rows=((plen - 1) // T) * T,
+                            granule_rows=T)
+                        if adm is not None:
+                            adm.attrs["matched"] = matched
+                    hit_c.inc(matched)
+                    miss_c.inc(plen - matched)
+                    slots[i] = _Slot(r, pos=plen, target=target,
+                                     t_arrive=arrive_wall[r.uid], plen=plen,
+                                     filled=matched, prefilling=True)
+                    n_adm += 1
 
-            # one decode step over the fully-prefilled slots
-            occ_h.observe(len(active) / self.batch)
-            pref = [i for i, s in enumerate(slots)
-                    if s is not None and s.prefilling]
-            tokens = np.zeros((self.batch, 1), np.int32)
-            slot_pos = np.zeros((self.batch,), np.int32)
-            for i in active:
-                s = slots[i]
-                tokens[i, 0] = s.req.out_tokens[-1]
-                slot_pos[i] = s.pos
-                self.cache.append(i, s.pos)
-            if pref:
-                # mask mid-prefill slots: their decode rows are inactive
-                # (slot_pos 0) and must write the scratch block, not the
-                # real block their table maps at row 0
-                tbl = self.cache.tables.copy()
-                tbl[pref] = -1
-                tables = jnp.asarray(tbl)
-            else:
-                tables = self.cache.device_tables()
-            t0 = time.perf_counter()
-            with tracer.span("serve.decode_step", n_active=len(active),
-                             queued=len(queue), prefilling=len(pref)):
-                logits, greedy, self.cache.state = self._decode(
-                    self.params, self.cache.state, jnp.asarray(tokens),
-                    tables, jnp.asarray(slot_pos))
-                if temperature <= 0:
-                    toks = jax.block_until_ready(greedy)
-                else:
-                    toks = np.zeros((self.batch,), np.int64)
-                    for i in active:
-                        s = slots[i]
-                        key = _request_key(base_key, s.req.uid)
-                        step_key = jax.random.fold_in(
-                            key, len(s.req.out_tokens))
-                        toks[i] = int(jax.block_until_ready(sample(
-                            logits[i:i + 1], step_key, temperature))[0])
-            dec_h.observe((time.perf_counter() - t0) * 1e3)
-            clock += 1.0
-            for i in active:
-                s = slots[i]
-                s.pos += 1
-                s.req.out_tokens.append(int(toks[i]))
-                _keep_logits(s.req, logits, i)
-                if len(s.req.out_tokens) >= s.target:
-                    finish(i)
-        qdepth.set(0)
+                # one prefill chunk for the oldest mid-prefill slot
+                pref = [i for i, s in enumerate(slots)
+                        if s is not None and s.prefilling]
+                if pref:
+                    i = min(pref, key=lambda j: (slots[j].req.arrival,
+                                                 slots[j].req.uid))
+                    s = slots[i]
+                    r = s.req
+                    prompt = np.asarray(r.prompt, np.int32).reshape(-1)
+                    with tracer.span("serve.prefill_chunk", uid=r.uid) as pc:
+                        if not s.started:
+                            # last chance to share: a producer that was
+                            # still mid-prefill at our admission has
+                            # registered its completed chunks by now —
+                            # graft them on while this slot has written
+                            # nothing
+                            grown = self.cache.extend_match(
+                                i, prompt,
+                                max_match_rows=((s.plen - 1) // T) * T,
+                                granule_rows=T)
+                            if grown > s.filled:
+                                hit_c.inc(grown - s.filled)
+                                miss_c.inc(s.filled - grown)
+                                s.filled = grown
+                            s.started = True
+                        start = s.filled
+                        n = min(T, s.plen - start)
+                        if pc is not None:
+                            pc.attrs.update(start=start, n_tokens=n)
+                        width = next_pow2(n)
+                        self.cache.extend_to(i, start + n)
+                        toks = np.zeros((1, width), np.int32)
+                        toks[0, :n] = prompt[start:start + n]
+                        logits, greedy, self.cache.state = self._get_chunk(
+                            width)(self.params, self.cache.state,
+                                   jnp.asarray(toks),
+                                   jnp.asarray(self.cache.tables[i:i + 1]),
+                                   jnp.int32(start), jnp.int32(n))
+                        s.filled = start + n
+                        # register incrementally: rows in completed
+                        # absolute chunks are final (later chunks never
+                        # rewrite them), so a prompt arriving mid-prefill
+                        # can already share them.  Only FULL aligned
+                        # chunks qualify — rows of a final partial chunk
+                        # ran at a different dispatch width, so their low
+                        # bits are not what a sharing consumer's oracle
+                        # would produce.
+                        self.cache.register_prefix(i, prompt,
+                                                   (s.filled // T) * T)
+                    clock += 1.0
+                    if s.filled >= s.plen:
+                        first = self._first_token("chunk", logits, greedy,
+                                                  r.uid, temperature,
+                                                  base_key)
+                        with tracer.span("serve.readback", n_tokens=1) as rb:
+                            tok = int(first[0])
+                            r.ttft_ms = _since_ms(arrive_wall[r.uid])
+                            ttft_h.observe(r.ttft_ms)
+                            r.out_tokens.append(tok)
+                            _keep_logits(r, logits, 0)
+                            s.prefilling = False
+                            done = len(r.out_tokens) >= s.target
+                            if done:
+                                finish(i)
+                            if rb is not None:
+                                rb.attrs["finished"] = int(done)
+                    drain_arrivals()
+
+                active = [i for i, s in enumerate(slots)
+                          if s is not None and not s.prefilling]
+                if not active:
+                    if any(s is not None for s in slots):
+                        continue        # prefill chunks still in flight
+                    if pending:
+                        # idle: jump the virtual clock to the next arrival
+                        clock = max(clock, pending[0].arrival)
+                        continue
+                    if queue:
+                        continue        # admission will retry (or raise)
+                    break
+
+                # one decode step over the fully-prefilled slots
+                self._decode_and_read(slots, active, temperature, base_key,
+                                      finish, dec_h, occ_h)
+                clock += 1.0
         return results
 
 
@@ -681,7 +714,7 @@ class CohortScheduler(_SchedulerBase):
     def run(self, requests: List[Request], temperature: float = 0.0,
             max_steps: int = 64) -> Dict[int, List[int]]:
         tracer = get_tracer()
-        ttft_h, dec_h, occ_h, qdepth, req_c, tok_c = self._metric_handles()
+        ttft_h, dec_h, occ_h, req_c, tok_c = self._metric_handles()
 
         pending = deque(sorted(requests, key=lambda r: (r.arrival, r.uid)))
         queue: deque = deque()
@@ -700,7 +733,6 @@ class CohortScheduler(_SchedulerBase):
                 continue
             active = [queue.popleft()
                       for _ in range(min(self.batch, len(queue)))]
-            qdepth.set(len(queue))
             occ_h.observe(len(active) / self.batch)
             with tracer.span("serve.batch", n_active=len(active),
                              queued=len(queue)):
@@ -753,5 +785,4 @@ class CohortScheduler(_SchedulerBase):
                     results[r.uid] = r.out_tokens
                     req_c.inc()
                     tok_c.inc(len(r.out_tokens))
-        qdepth.set(0)
         return results

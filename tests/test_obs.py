@@ -103,6 +103,71 @@ def test_tracer_clear_resets_trace_id():
     assert t.spans() == [] and t.trace_id != old
 
 
+def test_spans_record_while_a_profiler_session_runs(tmp_path):
+    """A tracer nobody enabled records while a JAX profiler session is
+    active, nests as usual, and stops recording when the session ends."""
+    import jax
+    t = Tracer()
+    with t.span("before") as sp:
+        assert sp is None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with t.span("serve.step", queued=3) as step:
+            with t.span("serve.readback", n_tokens=2) as rb:
+                assert rb.parent_id == step.span_id
+    finally:
+        jax.profiler.stop_trace()
+    with t.span("after") as sp:
+        assert sp is None
+    assert t.span("a") is t.span("b")
+    spans = t.spans()
+    assert [s.name for s in spans] == ["serve.readback", "serve.step"]
+    assert spans[0].attrs == {"n_tokens": 2}
+    assert spans[1].attrs == {"queued": 3}
+    assert all(s.dur_us >= 0 for s in spans)
+
+
+def test_span_open_across_start_or_stop_trace_is_dropped(tmp_path):
+    import jax
+    t = Tracer()
+    with t.span("across_start"):
+        jax.profiler.start_trace(str(tmp_path))
+        with t.span("inside_1"):
+            pass
+    try:
+        with t.span("across_stop"):
+            with t.span("inside_2"):
+                pass
+            jax.profiler.stop_trace()
+    finally:
+        if jax.profiler.TraceAnnotation.is_enabled():
+            jax.profiler.stop_trace()
+    assert [s.name for s in t.spans()] == ["inside_1", "inside_2"]
+
+
+def test_profiled_spans_land_in_the_host_plane_by_bare_name(tmp_path):
+    """Each span is a TraceAnnotation under its bare name: attributes stay
+    on the in-memory span and never split one name into many."""
+    import jax
+    t = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for uid in range(3):
+            with t.span("serve.step", queued=uid):
+                with t.span("serve.admit", uid=uid, waited_ms=1.5):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    names = [e.name for p in data.planes if p.name == "/host:CPU"
+             for ln in p.lines for e in ln.events
+             if e.name.startswith("serve.")]
+    assert sorted(names) == ["serve.admit"] * 3 + ["serve.step"] * 3
+    assert [s.attrs["uid"] for s in t.spans()
+            if s.name == "serve.admit"] == [0, 1, 2]
+
+
 # --- metrics ----------------------------------------------------------------
 
 def test_counter_gauge_basics():

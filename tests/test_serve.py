@@ -611,6 +611,72 @@ def test_requested_logits_are_the_rows_tokens_came_from(served, chunk):
     assert reqs[2].logits is None
 
 
+#: the children a ``serve.step`` span may hold
+STEP_CHILDREN = {"serve.admit", "serve.prefill", "serve.prefill_chunk",
+                 "serve.decode_step", "serve.device_wait", "serve.sample",
+                 "serve.readback"}
+
+
+@pytest.mark.parametrize("chunk,temperature", [(None, 0.0), (16, 0.0),
+                                               (16, 0.7)])
+def test_scheduler_span_tree_under_a_profiler_session(served, chunk,
+                                                      temperature, tmp_path):
+    """Under a profiler session every scheduler iteration is one
+    ``serve.step`` whose phases are its children, no longer than it in
+    sum; readback counts every token appended.  Without a session nothing
+    is recorded."""
+    from repro.obs.trace import tracer
+    cfg, _, params = served
+    reqs = _reqs(cfg, lens=(21, 7, 40, 12), max_new=(3, 5, 2, 4), seed=5)
+    for r, a in zip(reqs, (0, 0, 2, 3)):
+        r.arrival = a
+    sched = ContinuousScheduler(cfg, params, batch=2, max_seq=64,
+                                block_len=8, chunk_tokens=chunk,
+                                prefix_cache=chunk is not None)
+    tr = tracer()
+    tr.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = sched.run(reqs, temperature=temperature)
+    finally:
+        jax.profiler.stop_trace()
+    spans = tr.spans()
+    tr.clear()
+
+    steps = {s.span_id: s for s in spans if s.name == "serve.step"}
+    assert steps
+    for st in steps.values():
+        assert set(st.attrs) == {"active", "queued", "prefilling"}
+    children = [s for s in spans if s.name != "serve.step"]
+    assert all(s.parent_id in steps for s in children)
+    assert {s.name for s in children} <= STEP_CHILDREN
+    for sid, st in steps.items():
+        mine = [s for s in children if s.parent_id == sid]
+        assert sum(s.dur_us for s in mine) <= st.dur_us
+        if any(s.name == "serve.decode_step" for s in mine):
+            assert {"serve.device_wait", "serve.readback"} <= {
+                s.name for s in mine}
+    names = {s.name for s in children}
+    prefill = "serve.prefill" if chunk is None else "serve.prefill_chunk"
+    assert {"serve.admit", prefill, "serve.decode_step", "serve.device_wait",
+            "serve.readback"} <= names
+    assert ("serve.sample" in names) == (temperature > 0)
+    waits = {s.attrs["on"] for s in children if s.name == "serve.device_wait"}
+    assert waits == {"decode", "prefill" if chunk is None else "chunk"}
+    admits = [s for s in children if s.name == "serve.admit"]
+    assert sorted(s.attrs["uid"] for s in admits) == [r.uid for r in reqs]
+    assert all(s.attrs["waited_ms"] >= 0 and s.attrs["matched"] >= 0
+               for s in admits)
+    readback = [s for s in children if s.name == "serve.readback"]
+    assert sum(s.attrs["n_tokens"] for s in readback) == \
+        sum(len(v) for v in out.values())
+    assert sum(s.attrs["finished"] for s in readback) == len(reqs)
+
+    again = _reqs(cfg, lens=(21, 7, 40, 12), max_new=(3, 5, 2, 4), seed=5)
+    sched.run(again, temperature=temperature)
+    assert tr.spans() == []
+
+
 def test_prefix_sharing_hits_and_is_bit_identical(served):
     """Requests sharing a 32-token prefix: the prefix cache must serve
     later prefills from shared blocks (hit_tokens > 0) without changing
