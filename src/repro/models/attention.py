@@ -244,33 +244,83 @@ def attend_chunked(q, k, v, idx_map, *, causal: bool, window: int,
 # Decode attention (one new token against the cache)
 # ---------------------------------------------------------------------------
 
+def group_size(idx_map, n_kv: int) -> Optional[int]:
+    """Query heads per KV head when ``idx_map`` is the plain GQA grouping
+    ``repeat(arange(n_kv), G)``, else None.  Padded q heads (tensor
+    parallelism) clamp to the last KV head, which is no clean grouping."""
+    if idx_map.size % n_kv:
+        return None
+    g = idx_map.size // n_kv
+    return g if np.array_equal(idx_map, np.repeat(np.arange(n_kv), g)) \
+        else None
+
+
+def _attend_view(qf, k_cache, v_cache, mask, idx_map):
+    """Masked full-softmax attention over a (B,W,KV,hd) cache view.
+
+    qf: (B,Hp,hd) for one query per row (decode) or (B,C,Hp,hd) for C,
+    already scaled; mask: (B,W) or (B,C,W) to match.  Returns qf's shape in
+    float32.  Under a plain grouping each KV head is contracted once with
+    the G query heads that share it; only a padded head map gathers a
+    head-repeated (B,W,Hp,hd) copy of K and V.  Either way the cache-length
+    axis of the view and of the logits stays on "kvlen" (without it XLA
+    un-shards W and the decode_32k view costs 8.6 GB/chip)."""
+    # decode passes no query axis: a unit one there costs the step a
+    # relayout of the logits (a quarter more temporaries at batch 128)
+    c = "c" if qf.ndim == 4 else ""
+    hd = qf.shape[-1]
+    nkv = k_cache.shape[2]
+    g = group_size(idx_map, nkv)
+    if g is not None:
+        k = logical(k_cache, "batch", "kvlen", None, None)
+        v = logical(v_cache, "batch", "kvlen", None, None)
+        qg = qf.reshape(qf.shape[:-2] + (nkv, g, hd))
+        logits = jnp.einsum(f"b{c}kgd,bwkd->bkg{c}w", qg, k,
+                            preferred_element_type=jnp.float32)
+        logits = logical(logits, "batch", *[None] * (logits.ndim - 2),
+                         "kvlen")
+        logits = jnp.where(mask[:, None, None], logits, NEG)
+        p = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum(f"bkg{c}w,bwkd->b{c}kgd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(qf.shape)
+    k_rep = jnp.take(k_cache, idx_map, axis=2)                # (B,W,Hp,hd)
+    v_rep = jnp.take(v_cache, idx_map, axis=2)
+    k_rep = logical(k_rep, "batch", "kvlen", None, None)
+    v_rep = logical(v_rep, "batch", "kvlen", None, None)
+    logits = jnp.einsum(f"b{c}hd,bwhd->bh{c}w", qf, k_rep,
+                        preferred_element_type=jnp.float32)
+    logits = logical(logits, "batch", *[None] * (logits.ndim - 2), "kvlen")
+    logits = jnp.where(mask[:, None], logits, NEG)
+    p = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum(f"bh{c}w,bwhd->b{c}hd", p.astype(v_rep.dtype), v_rep,
+                      preferred_element_type=jnp.float32)
+
+
+def _view_mask(pos_cache, q_positions, window, global_flag):
+    """(B,C,W): cache rows in [0, q_position], inside the window unless the
+    layer is global.  pos_cache: (B,W); q_positions: (B,C)."""
+    pos = pos_cache[:, None, :]
+    qp = q_positions[:, :, None]
+    mask = (pos >= 0) & (pos <= qp)
+    if window > 0:
+        wmask = pos > (qp - window)
+        if global_flag is not None:
+            wmask = wmask | global_flag
+        mask &= wmask
+    return mask
+
+
 def attend_decode(q, k_cache, v_cache, pos_cache, idx_map, *,
                   q_position, window: int = 0,
                   scale: Optional[float] = None, global_flag=None):
     """q: (B,1,Hp,hd); caches: (B,W,KV,hd); pos_cache: (B,W) absolute
     positions (-1 empty).  q_position: (B,) absolute position of the query."""
-    b, _, hp, hd = q.shape
+    hd = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     qf = q[:, 0] * jnp.asarray(scale, q.dtype)                # (B,Hp,hd)
-    k_rep = jnp.take(k_cache, idx_map, axis=2)                # (B,W,Hp,hd)
-    v_rep = jnp.take(v_cache, idx_map, axis=2)
-    # keep the cache-length sharding through the GQA gather (without this
-    # XLA un-shards W and the decode_32k repeat costs 8.6 GB/chip)
-    k_rep = logical(k_rep, "batch", "kvlen", None, None)
-    v_rep = logical(v_rep, "batch", "kvlen", None, None)
-    logits = jnp.einsum("bhd,bwhd->bhw", qf, k_rep,
-                        preferred_element_type=jnp.float32)
-    logits = logical(logits, "batch", None, "kvlen")
-    mask = (pos_cache >= 0) & (pos_cache <= q_position[:, None])
-    if window > 0:
-        wmask = pos_cache > (q_position[:, None] - window)
-        if global_flag is not None:
-            wmask = wmask | global_flag
-        mask &= wmask
-    logits = jnp.where(mask[:, None, :], logits, NEG)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhw,bwhd->bhd", p.astype(v_rep.dtype), v_rep,
-                     preferred_element_type=jnp.float32)
+    mask = _view_mask(pos_cache, q_position[:, None], window, global_flag)
+    out = _attend_view(qf, k_cache, v_cache, mask[:, 0], idx_map)
     return out[:, None].astype(q.dtype)                       # (B,1,Hp,hd)
 
 
@@ -290,26 +340,11 @@ def attend_prefix(q, k_cache, v_cache, pos_cache, idx_map, *,
     W regardless of how prefill was chunked, so per-row outputs are
     bit-identical across chunk sizes and shared-prefix admissions — the
     property the chunked-prefill equivalence tests pin."""
-    b, c, hp, hd = q.shape
+    hd = q.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     qf = q * jnp.asarray(scale, q.dtype)                      # (B,C,Hp,hd)
-    k_rep = jnp.take(k_cache, idx_map, axis=2)                # (B,W,Hp,hd)
-    v_rep = jnp.take(v_cache, idx_map, axis=2)
-    k_rep = logical(k_rep, "batch", "kvlen", None, None)
-    v_rep = logical(v_rep, "batch", "kvlen", None, None)
-    logits = jnp.einsum("bchd,bwhd->bhcw", qf, k_rep,
-                        preferred_element_type=jnp.float32)
-    mask = (pos_cache[:, None, :] >= 0) \
-        & (pos_cache[:, None, :] <= q_positions[:, :, None])  # (B,C,W)
-    if window > 0:
-        wmask = pos_cache[:, None, :] > (q_positions[:, :, None] - window)
-        if global_flag is not None:
-            wmask = wmask | global_flag
-        mask &= wmask
-    logits = jnp.where(mask[:, None, :, :], logits, NEG)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhcw,bwhd->bchd", p.astype(v_rep.dtype), v_rep,
-                     preferred_element_type=jnp.float32)
+    mask = _view_mask(pos_cache, q_positions, window, global_flag)
+    out = _attend_view(qf, k_cache, v_cache, mask, idx_map)
     return out.astype(q.dtype)                                # (B,C,Hp,hd)
 
 

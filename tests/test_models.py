@@ -59,6 +59,83 @@ def test_attend_chunked_head_padding_exact():
 
 
 # ---------------------------------------------------------------------------
+# decode / prefix attention over a cache view: grouped heads == head gather
+# ---------------------------------------------------------------------------
+
+GQA = [(12, 2), (6, 2), (4, 2), (4, 4)]
+
+
+def _view_case(fn, dtype, h, kvh, q_heads, seed=0):
+    """Inputs for ``attend_decode`` (C=1) or ``attend_prefix`` (C=5) over a
+    ragged (B=3, W=24) view: row 0 holds 24 positions, row 1 holds 9 with
+    -1 rows after them, row 2 is empty."""
+    b, w, d = 3, 24, 16
+    c = 1 if fn == "decode" else 5
+    ks = jax.random.split(key(seed), 3)
+    q = jax.random.normal(ks[0], (b, c, q_heads, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, w, kvh, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, w, kvh, d)).astype(dtype)
+    pos = np.full((b, w), -1, np.int32)
+    pos[0] = np.arange(w)
+    pos[1, :9] = np.arange(9)
+    last = np.asarray([w - 1, 8, 0], np.int32)
+    q_pos = last[:, None] - np.arange(c - 1, -1, -1, dtype=np.int32)
+
+    def call(idx_map, qq=q):
+        if fn == "decode":
+            return attn.attend_decode(qq, k, v, jnp.asarray(pos), idx_map,
+                                      q_position=jnp.asarray(q_pos[:, 0]))
+        return attn.attend_prefix(qq, k, v, jnp.asarray(pos), idx_map,
+                                  q_positions=jnp.asarray(q_pos))
+    return q, call
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("fn", ["decode", "prefix"])
+@pytest.mark.parametrize("h,kvh", GQA)
+def test_grouped_view_attention_matches_head_gather(h, kvh, fn, dtype):
+    """The grouped contraction equals the head-gather path, which a padded
+    head map takes: its first h heads are the same heads over the same
+    KV heads.  Exact in bf16, within 1e-6 in fp32."""
+    q, call = _view_case(fn, dtype, h, kvh, h)
+    assert attn.group_size(attn.kv_index_map(h, kvh, h), kvh) == h // kvh
+    grouped = call(attn.kv_index_map(h, kvh, h))
+    q_pad = jnp.concatenate([q, jnp.zeros(q.shape[:2] + (2, q.shape[3]),
+                                          q.dtype)], axis=2)
+    gathered = call(attn.kv_index_map(h, kvh, h + 2), q_pad)[:, :, :h]
+    assert grouped.dtype == gathered.dtype == dtype
+    if dtype == jnp.bfloat16:
+        np.testing.assert_array_equal(np.asarray(grouped.astype(jnp.float32)),
+                                      np.asarray(gathered.astype(jnp.float32)))
+    else:
+        np.testing.assert_allclose(grouped, gathered, rtol=0, atol=1e-6)
+
+
+def _primitives(jaxpr):
+    """Every primitive in ``jaxpr``, nested jaxprs (``jnp.take``'s jit) too."""
+    names = set()
+    for e in jaxpr.eqns:
+        names.add(e.primitive.name)
+        for sub in jax.core.jaxprs_in_params(e.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("fn", ["decode", "prefix"])
+@pytest.mark.parametrize("h,kvh,h_pad", [c + (c[0],) for c in GQA]
+                         + [(4, 2, 6), (12, 2, 14)])
+def test_view_attention_gathers_heads_only_for_padded_maps(h, kvh, h_pad, fn):
+    """The head gather is chosen from the static head map: a plain grouping
+    traces no gather, a padded map (clamped to the last KV head) does."""
+    q, call = _view_case(fn, jnp.float32, h, kvh, h_pad)
+    idx_map = attn.kv_index_map(h, kvh, h_pad)
+    prims = _primitives(jax.make_jaxpr(lambda qq: call(idx_map, qq))(q).jaxpr)
+    padded = h_pad != h
+    assert ("gather" in prims) == padded
+    assert (attn.group_size(idx_map, kvh) is None) == padded
+
+
+# ---------------------------------------------------------------------------
 # recurrent blocks: chunkwise == stepwise
 # ---------------------------------------------------------------------------
 

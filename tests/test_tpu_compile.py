@@ -13,6 +13,7 @@ this file loads the TPU compiler.
 """
 import dataclasses
 import os
+import re
 import sys
 
 import jax
@@ -98,6 +99,26 @@ def test_paged_decode_step_compiles_for_v5e(qwen_2_layers, one_chip,
         _i32(one_chip, 4))
     assert lowered.out_info[0].shape == (4, cfg.vocab)
     lowered.compile()
+
+
+def test_paged_decode_step_contracts_grouped_heads_for_v5e(
+        qwen_2_layers, one_chip, no_persistent_cache):
+    """At the decode cell's batch and view (128 slots x 65 blocks of 16 rows,
+    an arena of 128 x 1,040 rows plus the scratch block) the step contracts
+    each KV head with its 6 query heads: the layer scan holds no head-repeat
+    ``jnp.take`` of the view (it lowers to per-head copy loops into a
+    materialised (128, 1040, 12, 128) tensor per layer; the token embedding's
+    ``jnp.take`` lies outside the scan), and temporaries stay under 400 MB
+    (775 MB with the gather)."""
+    cfg, model, params = qwen_2_layers
+    b, mb, bl = 128, 65, 16
+    paged = _paged(cfg, one_chip, n_blocks=b * mb + 1, block_len=bl)
+    compiled = jax.jit(model.decode_paged, donate_argnums=(1,)).lower(
+        params, paged, _i32(one_chip, b, 1), _i32(one_chip, b, mb),
+        _i32(one_chip, b)).compile()
+    assert not re.search(r'op_name="[^"]*/while/[^"]*jit\(_take\)',
+                         compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
 
 
 def test_prefill_chunk_compiles_for_v5e(qwen_2_layers, one_chip,
