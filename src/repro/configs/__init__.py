@@ -10,6 +10,7 @@ from ..core.config import ArchConfig
 _MODULES = {
     "command-r-35b": "command_r_35b",
     "deepseek-67b": "deepseek_67b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "phi3-mini-3.8b": "phi3_mini_3_8b",
     "qwen2-1.5b": "qwen2_1_5b",
     "xlstm-1.3b": "xlstm_1_3b",

@@ -23,12 +23,22 @@ class MoEConfig:
     top_k: int = 0
     n_shared: int = 0             # always-on shared experts (qwen2-moe style)
     d_ff_expert: int = 0          # hidden dim of each routed expert
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # 0 => no capacity: no token is dropped
     router_aux_weight: float = 0.001
+    norm_topk: bool = True        # renormalise the top-k weights to sum to 1
+    shared_gate: bool = True      # shared experts behind a sigmoid gate
+    # the routed experts this chip holds: [expert_lo, expert_lo + n_held);
+    # the router still scores all n_experts (n_held 0 => all of them)
+    expert_lo: int = 0
+    n_held: int = 0
 
     @property
     def enabled(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -55,10 +65,26 @@ class AttnConfig:
     rope_theta: float = 10000.0
     qkv_bias: bool = False
     softcap: float = 0.0          # logit soft-capping (0 => off)
+    # multi-head latent attention (deepseek-v2); kv_lora_rank 0 => off.
+    # Each token caches one latent row: kv_lora_rank + qk_rope_dim wide.
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0          # per-head query/key width without RoPE
+    qk_rope_dim: int = 0          # RoPE width (one key row shared by heads)
+    v_head_dim: int = 0
+    # YaRN RoPE scaling (deepseek-v2's rope_scaling); factor 0 => off
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     @property
     def sub_quadratic(self) -> bool:
         return self.kind in ("sliding", "none")
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
 
 # ---------------------------------------------------------------------------
@@ -80,6 +106,8 @@ class ArchConfig:
     norm: str = "rmsnorm"         # rmsnorm | layernorm
     parallel_residual: bool = False   # command-r style parallel attn+mlp
     tie_embeddings: bool = False
+    # moe: leading layers with a dense d_ff MLP in place of the experts
+    n_dense_layers: int = 0
     attn: AttnConfig = field(default_factory=AttnConfig)
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
@@ -100,21 +128,30 @@ class ArchConfig:
         return self.n_enc_layers > 0
 
     def param_count(self) -> int:
-        """Analytic parameter count (unpadded, matches the published size)."""
+        """Analytic parameter count (unpadded, matches the published size).
+        For moe, routed experts count as held here (``moe.held``)."""
         d, hd = self.d_model, self.head_dim_
         nh, nkv = self.n_heads, self.n_kv_heads
-        attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d  # q,k,v,o
-        if self.attn.qkv_bias:
-            attn += (nh + 2 * nkv) * hd
+        a = self.attn
+        if a.mla:
+            r, qk = a.kv_lora_rank, a.qk_nope_dim + a.qk_rope_dim
+            attn = (d * nh * qk + d * (r + a.qk_rope_dim) + r
+                    + r * nh * (a.qk_nope_dim + a.v_head_dim)
+                    + nh * a.v_head_dim * d)
+        else:
+            attn = d * nh * hd + 2 * d * nkv * hd + nh * hd * d  # q,k,v,o
+            if a.qkv_bias:
+                attn += (nh + 2 * nkv) * hd
+        n_mat = 3 if self.act == "swiglu" else 2
+        dense_mlp = n_mat * d * self.d_ff
         if self.moe.enabled:
             e = self.moe
-            mlp = e.n_experts * 3 * d * e.d_ff_expert + d * e.n_experts  # experts + router
+            mlp = e.held * 3 * d * e.d_ff_expert + d * e.n_experts  # + router
             mlp += e.n_shared * 3 * d * e.d_ff_expert
-        elif self.d_ff > 0:
-            n_mat = 3 if self.act == "swiglu" else 2
-            mlp = n_mat * d * self.d_ff
+            if e.n_shared and e.shared_gate:
+                mlp += d
         else:
-            mlp = 0
+            mlp = dense_mlp
         if self.ssm.enabled and self.ssm.kind == "xlstm":
             # mLSTM block: up/z proj + headwise qkv + gates + down proj
             inner = self.ssm.expand * d
@@ -128,7 +165,8 @@ class ArchConfig:
             attn += 2 * d * inner + inner * self.ssm.d_state * 2 + inner * d
         per_layer = attn + mlp + 2 * d
         emb = self.vocab * d * (1 if self.tie_embeddings else 2)
-        total = self.n_layers * per_layer + emb + d
+        total = (self.n_layers * per_layer + emb + d
+                 + self.n_dense_layers * (dense_mlp - mlp))
         if self.is_encdec:
             # encoder layers: self-attn + mlp; decoder adds cross-attn (already in n_layers count)
             enc = self.n_enc_layers * (attn + mlp + 2 * d)
@@ -142,7 +180,7 @@ class ArchConfig:
             return self.param_count()
         active_cfg = dataclasses.replace(
             self, moe=dataclasses.replace(
-                self.moe, n_experts=self.moe.top_k))
+                self.moe, n_experts=self.moe.top_k, n_held=0))
         return active_cfg.param_count()
 
 

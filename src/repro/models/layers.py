@@ -11,6 +11,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.config import ArchConfig
 from ..distributed.sharding import Param, logical, axis_size
@@ -92,11 +93,13 @@ def embed(p, tokens, compute_dtype=jnp.bfloat16):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x, positions, theta: float = 10000.0):
-    """x: (B, S, H, D); positions: (B, S) int32."""
+def rope(x, positions, theta: float = 10000.0, inv_freq=None):
+    """x: (B, S, H, D); positions: (B, S) int32.  ``inv_freq`` (D/2,)
+    replaces the plain ``theta`` frequencies (YaRN, ``yarn_inv_freq``)."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+            if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     ang = positions[..., None].astype(jnp.float32) * freq      # (B, S, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
@@ -105,6 +108,35 @@ def rope(x, positions, theta: float = 10000.0):
     return jnp.concatenate(
         [xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],
         axis=-1).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor: 0.1 * mscale * ln(factor) + 1
+    (1 for factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's RoPE frequencies (dim/2,), as deepseek-v2 computes them:
+    the plain frequencies theta^(-2i/dim) where a dimension turns more than
+    ``beta_fast`` times over ``original_max`` positions, the plain ones
+    divided by ``factor`` where it turns fewer than ``beta_slow`` times,
+    and a linear ramp over the dimension index in between."""
+    i = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** i
+    inter = 1.0 / (factor * theta ** i)
+
+    def corr(rot):  # dimension index that turns ``rot`` times
+        return (dim * math.log(original_max / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(corr(beta_fast)), 0)
+    hi = min(math.ceil(corr(beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp                       # 1 => the plain frequency
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,15 @@ Design (scales to qwen3-moe-235b on a 256-chip pod):
 
 Token-choice top-k routing with capacity factor + load-balance aux loss
 (Switch-style).  Shared experts (qwen2-moe) fold into one fused dense MLP
-(concatenated hidden = exact) with a sigmoid gate.
+(concatenated hidden = exact) with a sigmoid gate, or none (deepseek-v2).
+
+With ``capacity_factor`` 0 no token is dropped: each held expert has room
+for every token of the call, so a token's output never depends on which
+other tokens share its step.  A layer may hold only some of the routed
+experts (``MoEConfig.expert_lo`` / ``n_held``: one chip's share under
+expert parallelism): the router still scores all of them and picks its
+top-k, and the layer adds only the held experts' part of the result.  It
+also returns how many of the call's valid tokens picked each expert.
 """
 from __future__ import annotations
 
@@ -39,19 +47,20 @@ def moe_init(key, cfg: ArchConfig):
     e = cfg.moe
     ks = jax.random.split(key, 6)
     n_e = padded_experts(cfg)
+    n_w = held_experts(cfg)[1]
     scale = 1.0 / math.sqrt(d)
     p = {
         "router": {"w": Param(
             jax.random.normal(ks[0], (d, n_e), jnp.float32) * scale,
             ("embed", None))},
         "w_gate": Param(
-            jax.random.normal(ks[1], (n_e, d, e.d_ff_expert), jnp.float32)
+            jax.random.normal(ks[1], (n_w, d, e.d_ff_expert), jnp.float32)
             * scale, ("experts", "embed", "expert_shard")),
         "w_up": Param(
-            jax.random.normal(ks[2], (n_e, d, e.d_ff_expert), jnp.float32)
+            jax.random.normal(ks[2], (n_w, d, e.d_ff_expert), jnp.float32)
             * scale, ("experts", "embed", "expert_shard")),
         "w_down": Param(
-            jax.random.normal(ks[3], (n_e, e.d_ff_expert, d), jnp.float32)
+            jax.random.normal(ks[3], (n_w, e.d_ff_expert, d), jnp.float32)
             / math.sqrt(e.d_ff_expert), ("experts", "expert_shard", "embed")),
     }
     if e.n_shared > 0:
@@ -61,10 +70,34 @@ def moe_init(key, cfg: ArchConfig):
             "up": linear_init(ks[5], d, ff_shared, ("embed", "mlp")),
             "down": linear_init(jax.random.fold_in(ks[5], 1), ff_shared, d,
                                 ("mlp", "embed")),
-            "sgate": linear_init(jax.random.fold_in(ks[4], 1), d, 1,
-                                 ("embed", None)),
         }
+        if e.shared_gate:
+            p["shared"]["sgate"] = linear_init(
+                jax.random.fold_in(ks[4], 1), d, 1, ("embed", None))
     return p
+
+
+def held_experts(cfg: ArchConfig) -> Tuple[int, int]:
+    """(first, count) of the routed experts whose weights this layer
+    holds: the configured share, else every (padded) expert."""
+    e = cfg.moe
+    if e.n_held:
+        if e.expert_lo < 0 or e.expert_lo + e.n_held > e.n_experts:
+            raise ValueError(f"held experts [{e.expert_lo}, "
+                             f"{e.expert_lo + e.n_held}) outside "
+                             f"{e.n_experts}")
+        return e.expert_lo, e.n_held
+    return 0, padded_experts(cfg)
+
+
+def capacity(cfg: ArchConfig, n_tokens: int) -> int:
+    """Rows each held expert computes for a call over ``n_tokens`` tokens
+    (padding included): every token when no token may be dropped."""
+    e = cfg.moe
+    if e.capacity_factor <= 0:
+        return n_tokens
+    return max(int(math.ceil(n_tokens * e.top_k / e.n_experts
+                             * e.capacity_factor)), 4)
 
 
 def padded_experts(cfg: ArchConfig) -> int:
@@ -76,10 +109,13 @@ def padded_experts(cfg: ArchConfig) -> int:
 
 
 def _local_moe(x_flat, router_w, w_gate, w_up, w_down, *, cfg: ArchConfig,
-               n_experts_total: int, e_local: int, lo, compute_dtype):
+               n_experts_total: int, e_local: int, lo, compute_dtype,
+               valid=None):
     """Dispatch/compute/combine for the experts [lo, lo+e_local).
 
-    x_flat: (T, d).  Returns (partial_out (T, d), aux_loss scalar)."""
+    x_flat: (T, d); valid: (T,) bool, the rows that are real tokens (None:
+    all).  Returns (partial_out (T, d), aux_loss scalar, picks
+    (n_experts_total,) int32: how many valid tokens picked each expert)."""
     e = cfg.moe
     t = x_flat.shape[0]
     k = e.top_k
@@ -90,7 +126,12 @@ def _local_moe(x_flat, router_w, w_gate, w_up, w_down, *, cfg: ArchConfig,
     logits = jnp.where(valid_expert[None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_ids = jax.lax.top_k(probs, k)                   # (T, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if e.norm_topk:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    ok = (jnp.ones((t,), jnp.int32) if valid is None
+          else valid.astype(jnp.int32))
+    picks = jnp.zeros((n_experts_total,), jnp.int32).at[
+        top_ids.reshape(-1)].add(jnp.repeat(ok, k))
 
     # --- aux load-balance loss (Switch): E * sum_e f_e * P_e
     f = jnp.zeros((n_experts_total,), jnp.float32).at[top_ids.reshape(-1)].add(
@@ -99,7 +140,7 @@ def _local_moe(x_flat, router_w, w_gate, w_up, w_down, *, cfg: ArchConfig,
     aux = e.n_experts * jnp.sum(f * pbar)
 
     # --- sort-based dispatch with capacity
-    cap = max(int(math.ceil(t * k / e.n_experts * e.capacity_factor)), 4)
+    cap = capacity(cfg, t)
     flat_e = top_ids.reshape(-1)                               # (T*k,)
     flat_w = top_p.reshape(-1)
     flat_tok = jnp.arange(t * k, dtype=jnp.int32) // k
@@ -139,27 +180,35 @@ def _local_moe(x_flat, router_w, w_gate, w_up, w_down, *, cfg: ArchConfig,
     out = jnp.zeros_like(x_flat)
     for j in range(k):
         out = out + y_pad[slot_tk[:, j]] * w_tk[:, j:j + 1]
-    return out, aux
+    return out, aux, picks
 
 
-def moe_apply(p, x, cfg: ArchConfig, compute_dtype=jnp.bfloat16):
-    """x: (B, S, d) -> (out (B, S, d), aux_loss)."""
+def moe_apply(p, x, cfg: ArchConfig, compute_dtype=jnp.bfloat16,
+              valid=None):
+    """x: (B, S, d); valid: (B, S) bool, the real tokens (None: all) ->
+    (out (B, S, d), aux_loss, picks (n_experts,) int32 per expert)."""
     b, s, d = x.shape
     e = cfg.moe
     rules = shd.current_rules()
-    n_total = p["w_gate"].shape[0]
+    n_total = p["router"]["w"].shape[1]
+    lo, n_held = held_experts(cfg)
+    valid = None if valid is None else valid.reshape(-1)
 
     if rules is None or rules.rules.get("experts") is None:
-        # single-device / unsharded path
-        out, aux = _local_moe(
+        # single-device / unsharded path: the held experts only
+        out, aux, picks = _local_moe(
             x.reshape(-1, d), p["router"]["w"],
             p["w_gate"].astype(compute_dtype),
             p["w_up"].astype(compute_dtype),
             p["w_down"].astype(compute_dtype),
-            cfg=cfg, n_experts_total=n_total, e_local=n_total, lo=0,
-            compute_dtype=compute_dtype)
+            cfg=cfg, n_experts_total=n_total, e_local=n_held, lo=lo,
+            compute_dtype=compute_dtype, valid=valid)
         out = out.reshape(b, s, d)
     else:
+        if n_held != n_total:
+            raise NotImplementedError(
+                "expert parallelism over a mesh needs every expert's "
+                "weights; a held share runs on one device")
         mesh = rules.mesh
         model_axis = rules.rules["experts"]
         batch_axes = rules.rules.get("batch")
@@ -181,18 +230,20 @@ def moe_apply(p, x, cfg: ArchConfig, compute_dtype=jnp.bfloat16):
             wu = gather(wu.astype(compute_dtype), axis=2)
             wd = gather(wd.astype(compute_dtype), axis=1)
             rank = jax.lax.axis_index(model_axis)
-            out, aux = _local_moe(
+            out, aux, picks = _local_moe(
                 x_l.reshape(-1, d), rw, wg, wu, wd, cfg=cfg,
                 n_experts_total=n_total, e_local=e_local,
                 lo=rank * e_local, compute_dtype=compute_dtype)
             out = jax.lax.psum(out, model_axis)
             aux = jax.lax.pmean(aux, model_axis)
-            return out.reshape(x_l.shape), aux
+            if batch_axes is not None:
+                picks = jax.lax.psum(picks, batch_axes)
+            return out.reshape(x_l.shape), aux, picks
 
-        out, aux = jax.shard_map(
+        out, aux, picks = jax.shard_map(
             block, mesh=mesh,
             in_specs=(xs, P(None, None), wspec_g, wspec_g, wspec_d),
-            out_specs=(xs, P()),
+            out_specs=(xs, P(), P()),
             check_vma=False,
         )(x, p["router"]["w"], p["w_gate"], p["w_up"], p["w_down"])
 
@@ -202,6 +253,8 @@ def moe_apply(p, x, cfg: ArchConfig, compute_dtype=jnp.bfloat16):
             linear(sh["up"], x, compute_dtype)
         hidden = logical(hidden, "batch", None, "mlp")
         shared_out = linear(sh["down"], hidden, compute_dtype)
-        sgate = jax.nn.sigmoid(linear(sh["sgate"], x, jnp.float32))
-        out = out + shared_out * sgate.astype(compute_dtype)
-    return logical(out, "batch", None, "residual"), aux
+        if "sgate" in sh:
+            sgate = jax.nn.sigmoid(linear(sh["sgate"], x, jnp.float32))
+            shared_out = shared_out * sgate.astype(compute_dtype)
+        out = out + shared_out
+    return logical(out, "batch", None, "residual"), aux, picks

@@ -9,7 +9,10 @@ Modes:
 Layer heterogeneity (xlstm's mLSTM/sLSTM mix, hymba's global/local attention
 mix) is expressed as per-layer flag arrays threaded through the scan, so the
 whole depth still compiles as ONE scanned layer (critical for compile time at
-95 layers).
+95 layers).  The one exception is moe's leading dense layers
+(``n_dense_layers``, deepseek-v2's ``first_k_dense_replace``): they hold a
+dense MLP where the others hold experts, so they are a stack of their own
+(``params["dense_layers"]``), scanned before the expert layers.
 """
 from __future__ import annotations
 
@@ -34,16 +37,18 @@ from .layers import (embed, embed_init, linear, linear_init, mlp, mlp_init,
 # Per-layer init
 # ---------------------------------------------------------------------------
 
-def _layer_init(key, cfg: ArchConfig):
+def _layer_init(key, cfg: ArchConfig, dense: bool = False):
+    """One layer; ``dense`` makes a moe model's leading dense layer."""
     ks = jax.random.split(key, 8)
     p: Dict[str, Any] = {}
     fam = cfg.family
     if fam in ("dense", "moe", "vlm", "hybrid"):
         p["ln_attn"] = norm_init(cfg.d_model, cfg.norm)
-        p["attn"] = attn.attn_init(ks[0], cfg)
+        p["attn"] = (attn.mla_init(ks[0], cfg) if cfg.attn.mla
+                     else attn.attn_init(ks[0], cfg))
         if not cfg.parallel_residual:
             p["ln_mlp"] = norm_init(cfg.d_model, cfg.norm)
-        if cfg.moe.enabled:
+        if cfg.moe.enabled and not dense:
             p["moe"] = moe_mod.moe_init(ks[1], cfg)
         elif cfg.d_ff > 0:
             p["mlp"] = mlp_init(ks[1], cfg)
@@ -105,11 +110,20 @@ def _z(*shape, dtype=jnp.float32):
     return jnp.zeros(shape, dtype)
 
 
+def kv_layout(cfg: ArchConfig) -> Tuple[int, int, int]:
+    """(heads, K width, V width) of a cached token: latent attention
+    caches one latent row (``attn.mla_row_width``) as one "head" beside an
+    empty V."""
+    if cfg.attn.mla:
+        return 1, attn.mla_row_width(cfg), 0
+    return cfg.n_kv_heads, cfg.head_dim_, cfg.head_dim_
+
+
 def init_state(cfg: ArchConfig, batch: int, budget: int,
                dtype=jnp.bfloat16) -> State:
     L, d = cfg.n_layers, cfg.d_model
     has_attn = cfg.family in ("dense", "moe", "vlm", "hybrid", "encdec")
-    nkv, hd = cfg.n_kv_heads, cfg.head_dim_
+    nkv, hk, hv = kv_layout(cfg)
     w = budget if has_attn else 0
     if cfg.family == "ssm":
         n_m, n_s = ssm_layer_counts(cfg)
@@ -129,8 +143,8 @@ def init_state(cfg: ArchConfig, batch: int, budget: int,
     else:
         mam = _z(L, 0, 0, 0)
     return State(
-        k=_z(L, batch, w, nkv, hd, dtype=dtype),
-        v=_z(L, batch, w, nkv, hd, dtype=dtype),
+        k=_z(L, batch, w, nkv, hk, dtype=dtype),
+        v=_z(L, batch, w, nkv, hv, dtype=dtype),
         kpos=jnp.full((L, batch, w), -1, jnp.int32),
         mlstm_c=ml_c, mlstm_n=ml_n, mlstm_m=ml_m, slstm=sl, mamba=mam,
         pos=jnp.zeros((batch,), jnp.int32),
@@ -162,24 +176,38 @@ def _constrain_state(st: State) -> State:
 # ---------------------------------------------------------------------------
 
 def _attn_block(p, x, cfg, positions, mode, cache, global_flag, cdt):
-    """Returns (out, new_cache).  cache = (k, v, kpos) single-layer or None."""
+    """Returns (out, new_cache).  cache = (k, v, kpos) single-layer or None.
+    Under latent attention ``k`` caches the latent rows and ``v`` is
+    empty."""
     window = cfg.attn.window
     hp = padded_heads(cfg)
     idx_map = attn.kv_index_map(cfg.n_heads, cfg.n_kv_heads, hp)
-    q, k, v = attn.qkv_project(p, x, cfg, positions, cdt)
+    mla = cfg.attn.mla
+    if mla:
+        q_nope, q_pe, k = attn.mla_project(p, x, cfg, positions, cdt)
+        v = k[..., :0]
+    else:
+        q, k, v = attn.qkv_project(p, x, cfg, positions, cdt)
     new_cache = cache
     if mode == "decode":
         ck, cv, cpos = cache
         ck, cv, cpos = attn.update_cache_layer(ck, cv, cpos, k, v, positions)
-        out_h = attn.attend_decode(
-            q, ck, cv, cpos, idx_map, q_position=positions[:, 0],
-            window=window, global_flag=global_flag)
+        if mla:
+            out_h = attn.mla_attend_decode(p, q_nope, q_pe, ck, cpos, cfg,
+                                           positions[:, 0], cdt)
+        else:
+            out_h = attn.attend_decode(
+                q, ck, cv, cpos, idx_map, q_position=positions[:, 0],
+                window=window, global_flag=global_flag)
         new_cache = (ck, cv, cpos)
     else:
-        causal = cfg.attn.kind != "none"
-        out_h = attn.attend_chunked(
-            q, k, v, idx_map, causal=causal, window=window,
-            chunk=cfg.attn.chunk, global_flag=global_flag)
+        if mla:
+            out_h = attn.mla_attend_self(p, q_nope, q_pe, k, cfg, cdt)
+        else:
+            out_h = attn.attend_chunked(
+                q, k, v, idx_map, causal=cfg.attn.kind != "none",
+                window=window, chunk=cfg.attn.chunk,
+                global_flag=global_flag)
         if mode == "prefill":
             ck, cv, cpos = cache
             w = ck.shape[1]
@@ -197,26 +225,28 @@ def _attn_block(p, x, cfg, positions, mode, cache, global_flag, cdt):
     return out, new_cache
 
 
-def _ffn_residual(p, x, h, attn_out, cfg: ArchConfig, cdt):
+def _ffn_residual(p, x, h, attn_out, cfg: ArchConfig, cdt, valid=None):
     """Residual + FFN tail shared by every attention family (dense / moe /
     vlm), in both the dense-cache and paged decode paths.  ``h`` is the
-    pre-attention normed input (reused by parallel-residual archs)."""
+    pre-attention normed input (reused by parallel-residual archs).
+    Returns (x, aux, picks): an expert layer's per-expert count of the
+    ``valid`` (B, S) tokens' picks, else None."""
     aux = jnp.zeros((), jnp.float32)
+    picks = None
+
+    def ffn(h):
+        nonlocal aux, picks
+        if "moe" in p:
+            ff, aux, picks = moe_mod.moe_apply(p["moe"], h, cfg, cdt, valid)
+            return ff
+        return mlp(p["mlp"], h, cfg.act, cdt)
+
     if cfg.parallel_residual:
-        if cfg.moe.enabled:
-            ff, aux = moe_mod.moe_apply(p["moe"], h, cfg, cdt)
-        else:
-            ff = mlp(p["mlp"], h, cfg.act, cdt)
-        x = x + attn_out + ff
+        x = x + attn_out + ffn(h)
     else:
         x = x + attn_out
-        h2 = norm(p["ln_mlp"], x)
-        if cfg.moe.enabled:
-            ff, aux = moe_mod.moe_apply(p["moe"], h2, cfg, cdt)
-        else:
-            ff = mlp(p["mlp"], h2, cfg.act, cdt)
-        x = x + ff
-    return logical(x, "batch", "seq", "residual"), aux
+        x = x + ffn(norm(p["ln_mlp"], x))
+    return logical(x, "batch", "seq", "residual"), aux, picks
 
 
 def make_layer_fn(cfg: ArchConfig, mode: str):
@@ -259,7 +289,7 @@ def make_layer_fn(cfg: ArchConfig, mode: str):
             x = x + mlp(p["mlp"], h2, cfg.act, cdt)
             return x, (new_cache, aux)
 
-        x, aux = _ffn_residual(p, x, h, attn_out, cfg, cdt)
+        x, aux, _ = _ffn_residual(p, x, h, attn_out, cfg, cdt)
         return x, (new_cache, aux)
 
     return layer
@@ -314,13 +344,18 @@ def transformer_init(key, cfg: ArchConfig):
     ks = jax.random.split(key, 5)
     L = cfg.n_layers
     n_m, n_s = ssm_layer_counts(cfg)
+    nd = cfg.n_dense_layers
     stacked = stack_init(_layer_init, ks[0], n_m if cfg.family == "ssm"
-                         else L, cfg)
+                         else L - nd, cfg)
     p = {
         "embed": embed_init(ks[1], padded_vocab(cfg), cfg.d_model),
         "layers": stacked,
         "ln_f": norm_init(cfg.d_model, cfg.norm),
     }
+    if nd > 0:
+        p["dense_layers"] = stack_init(
+            functools.partial(_layer_init, dense=True),
+            jax.random.fold_in(ks[0], 1), nd, cfg)
     if n_s > 0:
         p["slstm_layers"] = stack_init(_slstm_layer_init, ks[4], n_s, cfg)
     if not cfg.tie_embeddings:
@@ -443,6 +478,15 @@ def _remat_group(L: int) -> int:
     return best if L // best > 1 else 1
 
 
+def _layer_stacks(params, cfg: ArchConfig):
+    """(stacked params, first layer index, layer count) of each stack in
+    depth order: a moe model's leading dense layers, then the rest."""
+    nd = cfg.n_dense_layers
+    rest = jax.tree.leaves(params["layers"])[0].shape[0]
+    return ([(params["dense_layers"], 0, nd)] if nd > 0 else []) + [
+        (params["layers"], nd, rest)]
+
+
 def forward(params, cfg: ArchConfig, tokens, *, patches=None,
             mode: str = "train", state: Optional[State] = None,
             remat: bool = True, budget: Optional[int] = None):
@@ -486,10 +530,6 @@ def forward(params, cfg: ArchConfig, tokens, *, patches=None,
         # serving: the KV cache is a scan CARRY updated in place (XLA's
         # in-loop dynamic-update-slice aliasing) — stacking it through
         # scan xs/ys would hold 2-3 cache-sized temps per step
-        K, V, KP = state.k, state.v, state.kpos
-        xs = (params["layers"], state.mamba, flags,
-              jnp.arange(L, dtype=jnp.int32))
-
         def serve_body(carry, per):
             x, K, V, KP = carry
             p_l, mb_l, f_l, i = per
@@ -506,15 +546,25 @@ def forward(params, cfg: ArchConfig, tokens, *, patches=None,
             KP = jax.lax.dynamic_update_index_in_dim(KP, nc["kp"], i, 0)
             return (x, K, V, KP), (nc["mb"], aux)
 
-        (x, K, V, KP), (new_mb, auxs) = jax.lax.scan(
-            serve_body, (x, K, V, KP), xs)
+        carry = (x, state.k, state.v, state.kpos)
+        new_mb, auxs = [], []
+        for stack, first, n in _layer_stacks(params, cfg):
+            part = slice(first, first + n)
+            carry, (mb, aux) = jax.lax.scan(serve_body, carry, (
+                stack, state.mamba[part],
+                {k: v[part] for k, v in flags.items()},
+                jnp.arange(first, first + n, dtype=jnp.int32)))
+            new_mb.append(mb)
+            auxs.append(jnp.sum(aux))
+        x, K, V, KP = carry
         logits = unembed(params, cfg, x)
         new_state = State(
             k=K, v=V, kpos=KP,
             mlstm_c=state.mlstm_c, mlstm_n=state.mlstm_n,
-            mlstm_m=state.mlstm_m, slstm=state.slstm, mamba=new_mb,
+            mlstm_m=state.mlstm_m, slstm=state.slstm,
+            mamba=jnp.concatenate(new_mb),
             pos=positions[:, -1] + 1)
-        return logits, _constrain_state(new_state), jnp.sum(auxs)
+        return logits, _constrain_state(new_state), sum(auxs)
 
     # training path
     cache = _cache_tree(cfg, state)
@@ -523,6 +573,17 @@ def forward(params, cfg: ArchConfig, tokens, *, patches=None,
         p_l, c_l, f_l = per_layer
         f_l = dict(f_l, positions=positions)
         return layer_fn(x, (p_l, c_l, f_l))
+
+    aux_dense = jnp.zeros((), jnp.float32)
+    nd = cfg.n_dense_layers
+    if nd > 0:
+        x, (_, aux_d) = jax.lax.scan(scan_body, x, (
+            params["dense_layers"], jax.tree.map(lambda t: t[:nd], cache),
+            jax.tree.map(lambda t: t[:nd], flags)))
+        aux_dense = jnp.sum(aux_d)
+        cache = jax.tree.map(lambda t: t[nd:], cache)
+        flags = jax.tree.map(lambda t: t[nd:], flags)
+        L -= nd
 
     g = _remat_group(L) if remat else 1
     if g > 1:
@@ -544,7 +605,7 @@ def forward(params, cfg: ArchConfig, tokens, *, patches=None,
         x, (new_cache, auxs) = jax.lax.scan(
             scan_body, x, (params["layers"], cache, flags))
     logits = unembed(params, cfg, x)
-    return logits, None, jnp.sum(auxs)
+    return logits, None, jnp.sum(auxs) + aux_dense
 
 
 # ---------------------------------------------------------------------------
@@ -561,10 +622,18 @@ class PagedState(NamedTuple):
     (L, n_blocks, block_len, KV, hd); ``pos``: (n_blocks, block_len)
     absolute position of each row (-1 = empty).  Positions are identical
     across layers, so one plane serves the whole stack.  Block 0 is the
-    scratch block inactive slots write into (see models.attention)."""
+    scratch block inactive slots write into (see models.attention).
+    Under latent attention ``k`` is the latent arena, one "head" of
+    ``attn.mla_row_width`` columns, and ``v`` is empty (``kv_layout``).
+
+    ``picks`` is an output of the paged steps, never held in the arena:
+    how many of the step's tokens each expert layer routed to each expert,
+    (n_expert_layers, n_experts) int32, or None (no experts, or the
+    arena itself)."""
     k: jax.Array
     v: jax.Array
     pos: jax.Array
+    picks: Optional[jax.Array] = None
 
 
 def init_paged_state(cfg: ArchConfig, n_blocks: int, block_len: int,
@@ -574,9 +643,41 @@ def init_paged_state(cfg: ArchConfig, n_blocks: int, block_len: int,
             f"paged decode supports families {PAGED_FAMILIES}, "
             f"not {cfg.family!r}")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else dtype
-    shape = (cfg.n_layers, n_blocks, block_len, cfg.n_kv_heads, cfg.head_dim_)
-    return PagedState(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+    nkv, hk, hv = kv_layout(cfg)
+    lead = (cfg.n_layers, n_blocks, block_len, nkv)
+    return PagedState(k=jnp.zeros(lead + (hk,), dtype),
+                      v=jnp.zeros(lead + (hv,), dtype),
                       pos=jnp.full((n_blocks, block_len), -1, jnp.int32))
+
+
+def _paged_layers(params, cfg: ArchConfig, body, x, paged: PagedState):
+    """Run ``body(carry, (p_l, i))`` over every layer stack with the arena
+    as the carry; returns (x, K, V, picks of the expert layers)."""
+    carry, picks = (x, paged.k, paged.v), None
+    for stack, first, n in _layer_stacks(params, cfg):
+        carry, picks = jax.lax.scan(
+            body, carry, (stack, jnp.arange(first, first + n,
+                                            dtype=jnp.int32)))
+    return carry + (picks,)
+
+
+def _paged_attention(p_l, h, cfg: ArchConfig, positions, K, V, write,
+                     attend, cdt):
+    """Project one layer's queries and new cache rows, ``write`` the rows
+    into the whole arena, then ``attend``: (q, K, V) for standard
+    attention, or (q_nope, q_pe), K, V under latent attention.  Both index
+    the layer's blocks in the whole arena: a slice of the layer and its
+    write-back would cost a pass over the layer's arena each.
+    Returns (attention heads, K, V)."""
+    if cfg.attn.mla:
+        q_nope, q_pe, k_new = attn.mla_project(p_l["attn"], h, cfg,
+                                               positions, cdt)
+        q, v_new = (q_nope, q_pe), k_new[..., :0]
+    else:
+        q, k_new, v_new = attn.qkv_project(p_l["attn"], h, cfg, positions,
+                                           cdt)
+    K, V = write(K, V, k_new, v_new)
+    return attend(q, K, V), K, V
 
 
 def forward_paged_decode(params, cfg: ArchConfig, tokens, paged: PagedState,
@@ -588,7 +689,8 @@ def forward_paged_decode(params, cfg: ArchConfig, tokens, paged: PagedState,
     position.  Unlike the dense-cache decode, slots need NOT share a
     position — each writes at its own (block, row) and attends only rows
     whose gathered position is in [0, its own position].  Returns
-    (last-token logits, new PagedState)."""
+    (last-token logits, new PagedState); the state's ``picks`` count the
+    routing of the slots whose table row is in use."""
     if cfg.family not in PAGED_FAMILIES:
         raise NotImplementedError(
             f"paged decode supports families {PAGED_FAMILIES}, "
@@ -606,34 +708,37 @@ def forward_paged_decode(params, cfg: ArchConfig, tokens, paged: PagedState,
     blk = jnp.maximum(blk, 0)
     off = slot_pos % bl
     pos_blocks = paged.pos.at[blk, off].set(slot_pos)
+    live = (block_table[:, 0] >= 0)[:, None]            # (B, 1)
 
     hp = padded_heads(cfg)
     idx_map = attn.kv_index_map(cfg.n_heads, cfg.n_kv_heads, hp)
-    L = cfg.n_layers
 
     def body(carry, per):
         x, K, V = carry
         p_l, i = per
-        k_l = jax.lax.dynamic_index_in_dim(K, i, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(V, i, 0, keepdims=False)
-        h = norm(p_l["ln_attn"], x)
-        q, k_new, v_new = attn.qkv_project(p_l["attn"], h, cfg, positions,
-                                           cdt)
-        k_l, v_l = attn.append_paged_layer(k_l, v_l, k_new, v_new, blk, off)
-        out_h = attn.attend_paged(
-            q, k_l, v_l, pos_blocks, block_table, idx_map,
-            q_position=slot_pos, window=cfg.attn.window)
-        attn_o = attn.attn_out(p_l["attn"], out_h, cfg, cdt)
-        x, aux = _ffn_residual(p_l, x, h, attn_o, cfg, cdt)
-        K = jax.lax.dynamic_update_index_in_dim(K, k_l, i, 0)
-        V = jax.lax.dynamic_update_index_in_dim(V, v_l, i, 0)
-        return (x, K, V), aux
 
-    (x, K, V), _ = jax.lax.scan(
-        body, (x, paged.k, paged.v),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+        def write(K, V, k_new, v_new):
+            return attn.append_paged_layer(K, V, k_new, v_new, blk, off, i)
+
+        def attend(q, K, V):
+            if cfg.attn.mla:
+                kd, _, pd = attn.gather_paged_view(K, V, pos_blocks,
+                                                   block_table, i)
+                return attn.mla_attend_decode(p_l["attn"], *q, kd, pd, cfg,
+                                              slot_pos, cdt)
+            return attn.attend_paged(
+                q, K, V, pos_blocks, block_table, idx_map,
+                layer=i, q_position=slot_pos, window=cfg.attn.window)
+        h = norm(p_l["ln_attn"], x)
+        out_h, K, V = _paged_attention(p_l, h, cfg, positions, K, V, write,
+                                       attend, cdt)
+        attn_o = attn.attn_out(p_l["attn"], out_h, cfg, cdt)
+        x, _, picks = _ffn_residual(p_l, x, h, attn_o, cfg, cdt, live)
+        return (x, K, V), picks
+
+    x, K, V, picks = _paged_layers(params, cfg, body, x, paged)
     logits = unembed(params, cfg, x)
-    return logits[:, -1], PagedState(k=K, v=V, pos=pos_blocks)
+    return logits[:, -1], PagedState(k=K, v=V, pos=pos_blocks, picks=picks)
 
 
 def forward_paged_chunk(params, cfg: ArchConfig, tokens, paged: PagedState,
@@ -646,7 +751,8 @@ def forward_paged_chunk(params, cfg: ArchConfig, tokens, paged: PagedState,
     n_real: () int32 real-row count (1 <= n_real <= C).  Writes the real
     rows' K/V into the slot's blocks (pad rows land in scratch block 0
     with position -1, so they are never attended) and returns
-    (logits of row start+n_real-1, shape (1, vocab_p), new PagedState).
+    (logits of row start+n_real-1, shape (1, vocab_p), new PagedState,
+    whose ``picks`` count the real rows' routing).
 
     Numerics: K/V/FFN are per-row and attention goes through
     ``attend_prefix``'s full masked softmax over the gathered MB*BL view,
@@ -681,32 +787,34 @@ def forward_paged_chunk(params, cfg: ArchConfig, tokens, paged: PagedState,
 
     hp = padded_heads(cfg)
     idx_map = attn.kv_index_map(cfg.n_heads, cfg.n_kv_heads, hp)
-    L = cfg.n_layers
 
     def body(carry, per):
         x, K, V = carry
         p_l, i = per
-        k_l = jax.lax.dynamic_index_in_dim(K, i, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(V, i, 0, keepdims=False)
-        h = norm(p_l["ln_attn"], x)
-        q, k_new, v_new = attn.qkv_project(p_l["attn"], h, cfg, pos_q, cdt)
-        k_l = k_l.at[blk, off].set(k_new[0])
-        v_l = v_l.at[blk, off].set(v_new[0])
-        kd, vd, pd = attn.gather_paged_view(k_l, v_l, pos_blocks,
-                                            block_table)
-        out_h = attn.attend_prefix(q, kd, vd, pd, idx_map,
-                                   q_positions=pos_q,
-                                   window=cfg.attn.window)
-        attn_o = attn.attn_out(p_l["attn"], out_h, cfg, cdt)
-        x, aux = _ffn_residual(p_l, x, h, attn_o, cfg, cdt)
-        K = jax.lax.dynamic_update_index_in_dim(K, k_l, i, 0)
-        V = jax.lax.dynamic_update_index_in_dim(V, v_l, i, 0)
-        return (x, K, V), aux
 
-    (x, K, V), _ = jax.lax.scan(
-        body, (x, paged.k, paged.v),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+        def write(K, V, k_new, v_new):
+            return (K.at[i, blk, off].set(k_new[0]),
+                    V.at[i, blk, off].set(v_new[0]))
+
+        def attend(q, K, V):
+            kd, vd, pd = attn.gather_paged_view(K, V, pos_blocks,
+                                                block_table, i)
+            if cfg.attn.mla:
+                return attn.mla_attend_prefix(p_l["attn"], *q, kd, pd, cfg,
+                                              pos_q, cdt)
+            return attn.attend_prefix(q, kd, vd, pd, idx_map,
+                                      q_positions=pos_q,
+                                      window=cfg.attn.window)
+        h = norm(p_l["ln_attn"], x)
+        out_h, K, V = _paged_attention(p_l, h, cfg, pos_q, K, V, write,
+                                       attend, cdt)
+        attn_o = attn.attn_out(p_l["attn"], out_h, cfg, cdt)
+        x, _, picks = _ffn_residual(p_l, x, h, attn_o, cfg, cdt,
+                                    valid[None, :])
+        return (x, K, V), picks
+
+    x, K, V, picks = _paged_layers(params, cfg, body, x, paged)
     x_last = jax.lax.dynamic_slice_in_dim(
         x, jnp.maximum(n_real - 1, 0), 1, axis=1)       # (1, 1, d)
     logits = unembed(params, cfg, x_last)
-    return logits[:, -1], PagedState(k=K, v=V, pos=pos_blocks)
+    return logits[:, -1], PagedState(k=K, v=V, pos=pos_blocks, picks=picks)

@@ -66,7 +66,8 @@ def _clear_pos(pos, ids):
 @partial(jax.jit, donate_argnums=(0, 1, 2))
 def _copy_block(k, v, pos, src, dst):
     """Copy-on-write: duplicate arena block ``src`` into ``dst`` (all
-    layers + position rows).  Donated so the arena updates in place."""
+    layers + position rows; a latent arena's empty ``v`` copies nothing).
+    Donated so the arena updates in place."""
     return (k.at[:, dst].set(k[:, src]),
             v.at[:, dst].set(v[:, src]),
             pos.at[dst].set(pos[src]))
@@ -96,14 +97,15 @@ def scatter_prefill(paged: tfm.PagedState, k_dense, v_dense, pos_dense, ids
     """Scatter one request's dense prefill cache into its arena blocks.
 
     Pure (traceable) so schedulers can fuse it with the prefill forward
-    into one jitted dispatch.  k/v_dense: (L, 1, bucket, KV, hd);
+    into one jitted dispatch.  k/v_dense: (L, 1, bucket, KV, width), V's
+    width possibly other than K's (empty under latent attention);
     pos_dense: (bucket,) with pad rows already -1; ids: (nb,) target
     block ids, nb * block_len == bucket."""
-    L, _, bucket, kv, hd = k_dense.shape
+    L, _, bucket = k_dense.shape[:3]
     nb = ids.shape[0]
     bl = bucket // nb
-    k = k_dense[:, 0].reshape(L, nb, bl, kv, hd)
-    v = v_dense[:, 0].reshape(L, nb, bl, kv, hd)
+    k = k_dense[:, 0].reshape((L, nb, bl) + k_dense.shape[3:])
+    v = v_dense[:, 0].reshape((L, nb, bl) + v_dense.shape[3:])
     pos = pos_dense.reshape(nb, bl)
     return tfm.PagedState(k=paged.k.at[:, ids].set(k),
                           v=paged.v.at[:, ids].set(v),

@@ -57,6 +57,19 @@ per phase, never one per slot, each a child of the iteration's:
   serve.sample            sampling at temperature > 0; ``active``
   serve.readback          tokens to the host, appends, finished requests
                           freed; ``n_tokens``, ``finished``
+
+For a model with experts, each decode step and prefill chunk also returns
+how many of its tokens each expert layer routed to each expert.  While the
+tracer records, ``ContinuousScheduler`` counts those of the recorded
+dispatches into its registry, once a later wait has seen the device finish
+them (so reading them waits for nothing):
+
+  moe.assignments         counter, every top-k pick of a real token
+  moe.assignments_here    counter, the picks of experts held here
+  moe.expert_rows         counter, rows the held experts computed,
+                          padding included (``moe.capacity`` per expert)
+  moe.max_expert_load     histogram, per expert layer and dispatch, the
+                          most picks any held expert got
 """
 from __future__ import annotations
 
@@ -72,6 +85,7 @@ import numpy as np
 from ..core.config import ArchConfig
 from ..distributed import sharding as shd
 from ..models import build_model
+from ..models.moe import capacity, held_experts
 from ..obs import metrics as obs_metrics
 from ..obs.trace import get_tracer
 from .cache import PagedKVCache, next_pow2, scatter_prefill
@@ -277,6 +291,8 @@ class ContinuousScheduler(_SchedulerBase):
         self._extra_rows = int(cfg.n_patches or 0)
 
         model, rules_ = self.model, self.rules
+        # (routing counts, rows) of recorded dispatches not yet counted
+        self._picks: List = []
 
         def _decode(params, paged, tokens, tables, slot_pos):
             with shd.use_rules(rules_):
@@ -284,7 +300,8 @@ class ContinuousScheduler(_SchedulerBase):
                                                    tables, slot_pos)
             # fold the greedy pick into the same dispatch: one jit call
             # per decode step instead of decode + eager argmax
-            return logits, jnp.argmax(logits, axis=-1), paged
+            return (logits, jnp.argmax(logits, axis=-1),
+                    paged._replace(picks=None), paged.picks)
 
         self._decode = jax.jit(_decode, donate_argnums=(1,))
 
@@ -327,7 +344,8 @@ class ContinuousScheduler(_SchedulerBase):
                 with shd.use_rules(rules):
                     logits, paged = model.prefill_chunk(
                         params, paged, tokens, table, start, n_real)
-                return logits, jnp.argmax(logits, axis=-1), paged
+                return (logits, jnp.argmax(logits, axis=-1),
+                        paged._replace(picks=None), paged.picks)
 
             fn = self._chunk_fns[width] = jax.jit(chunk_step,
                                                   donate_argnums=(1,))
@@ -341,6 +359,32 @@ class ContinuousScheduler(_SchedulerBase):
                 (1, self.cfg.n_patches, self.cfg.d_model), jnp.float32)
         return batch
 
+    # -- routing counters ---------------------------------------------------
+
+    def _note_picks(self, picks, rows: int, span) -> None:
+        """Keep a dispatch's routing counts if its span was recorded."""
+        if span is not None and picks is not None:
+            self._picks.append((picks, rows))
+
+    def _count_picks(self) -> None:
+        """Count the kept routing counts into the registry.  Called after
+        a wait on a later dispatch, so the device has finished them."""
+        if not self._picks:
+            return
+        m = self.metrics
+        lo, n = held_experts(self.cfg)
+        load = m.histogram("moe.max_expert_load")
+        for picks, rows in self._picks:
+            c = np.asarray(picks)           # (expert layers, experts)
+            here = c[:, lo:lo + n]
+            m.counter("moe.assignments").inc(int(c.sum()))
+            m.counter("moe.assignments_here").inc(int(here.sum()))
+            m.counter("moe.expert_rows").inc(
+                c.shape[0] * n * capacity(self.cfg, rows))
+            for v in here.max(axis=1):
+                load.observe(int(v))
+        self._picks.clear()
+
     # -- main loop ----------------------------------------------------------
 
     def _first_token(self, on: str, logits, greedy, uid: int,
@@ -353,7 +397,9 @@ class ContinuousScheduler(_SchedulerBase):
                 greedy = sample(logits, jax.random.fold_in(
                     _request_key(base_key, uid), 0), temperature)
         with tracer.span("serve.device_wait", on=on):
-            return jax.block_until_ready(greedy)
+            first = jax.block_until_ready(greedy)
+        self._count_picks()
+        return first
 
     def _decode_and_read(self, slots, active, temperature, base_key,
                          finish, dec_h, occ_h) -> None:
@@ -363,7 +409,7 @@ class ContinuousScheduler(_SchedulerBase):
         scratch block, not the real block their table maps at row 0."""
         tracer = get_tracer()
         occ_h.observe(len(active) / self.batch)
-        with tracer.span("serve.decode_step", active=len(active)):
+        with tracer.span("serve.decode_step", active=len(active)) as ds:
             tokens = np.zeros((self.batch, 1), np.int32)
             slot_pos = np.zeros((self.batch,), np.int32)
             for i in active:
@@ -380,12 +426,14 @@ class ContinuousScheduler(_SchedulerBase):
                 tables = jnp.asarray(tbl)
             else:
                 tables = self.cache.device_tables()
-            logits, greedy, self.cache.state = self._decode(
+            logits, greedy, self.cache.state, picks = self._decode(
                 self.params, self.cache.state, jnp.asarray(tokens),
                 tables, jnp.asarray(slot_pos))
+            self._note_picks(picks, self.batch, ds)
         with tracer.span("serve.device_wait", on="decode"):
             toks = jax.block_until_ready(greedy if temperature <= 0
                                          else logits)
+        self._count_picks()
         if temperature > 0:
             with tracer.span("serve.sample", active=len(active)):
                 toks = np.zeros((self.batch,), np.int64)
@@ -634,11 +682,13 @@ class ContinuousScheduler(_SchedulerBase):
                         self.cache.extend_to(i, start + n)
                         toks = np.zeros((1, width), np.int32)
                         toks[0, :n] = prompt[start:start + n]
-                        logits, greedy, self.cache.state = self._get_chunk(
-                            width)(self.params, self.cache.state,
-                                   jnp.asarray(toks),
-                                   jnp.asarray(self.cache.tables[i:i + 1]),
-                                   jnp.int32(start), jnp.int32(n))
+                        (logits, greedy, self.cache.state,
+                         picks) = self._get_chunk(width)(
+                            self.params, self.cache.state,
+                            jnp.asarray(toks),
+                            jnp.asarray(self.cache.tables[i:i + 1]),
+                            jnp.int32(start), jnp.int32(n))
+                        self._note_picks(picks, width, pc)
                         s.filled = start + n
                         # register incrementally: rows in completed
                         # absolute chunks are final (later chunks never

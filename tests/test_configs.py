@@ -89,6 +89,7 @@ def test_full_config_matches_assignment(arch):
     expected = {
         "command-r-35b": (40, 8192, 64, 8, 22528, 256000),
         "deepseek-67b": (95, 8192, 64, 8, 22016, 102400),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
         "phi3-mini-3.8b": (32, 3072, 32, 32, 8192, 32064),
         "qwen2-1.5b": (28, 1536, 12, 2, 8960, 151936),
         "xlstm-1.3b": (48, 2048, 4, 4, 0, 50304),

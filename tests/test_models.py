@@ -225,7 +225,7 @@ def test_moe_output_finite_and_grad():
     cfg = _moe_cfg()
     p, _ = split_tree(moe_mod.moe_init(key(8), cfg))
     x = jax.random.normal(key(9), (2, 16, 32))
-    out, aux = moe_mod.moe_apply(p, x, cfg, compute_dtype=jnp.float32)
+    out, aux, _ = moe_mod.moe_apply(p, x, cfg, compute_dtype=jnp.float32)
     assert out.shape == x.shape
     assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(aux))
     g = jax.grad(lambda pp: moe_mod.moe_apply(pp, x, cfg,
@@ -239,7 +239,7 @@ def test_moe_aux_loss_balanced_router_is_one():
     p, _ = split_tree(moe_mod.moe_init(key(10), cfg))
     p["router"]["w"] = jnp.zeros_like(p["router"]["w"])  # uniform probs
     x = jax.random.normal(key(11), (4, 16, 32))
-    _, aux = moe_mod.moe_apply(p, x, cfg, compute_dtype=jnp.float32)
+    _, aux, _ = moe_mod.moe_apply(p, x, cfg, compute_dtype=jnp.float32)
     assert abs(float(aux) - 1.0) < 0.05
 
 
@@ -251,9 +251,10 @@ def test_moe_capacity_drops_tokens():
                                        d_ff_expert=16, capacity_factor=0.1))
     p, _ = split_tree(moe_mod.moe_init(key(12), cfg_big))
     x = jax.random.normal(key(13), (2, 64, 32))
-    out_big, _ = moe_mod.moe_apply(p, x, cfg_big, compute_dtype=jnp.float32)
-    out_small, _ = moe_mod.moe_apply(p, x, cfg_small,
-                                     compute_dtype=jnp.float32)
+    out_big, _, _ = moe_mod.moe_apply(p, x, cfg_big,
+                                      compute_dtype=jnp.float32)
+    out_small, _, _ = moe_mod.moe_apply(p, x, cfg_small,
+                                        compute_dtype=jnp.float32)
     assert float(jnp.linalg.norm(out_small)) < float(jnp.linalg.norm(out_big))
     assert bool(jnp.isfinite(out_small).all())
 

@@ -317,9 +317,9 @@ def test_attend_paged_matches_attend_decode():
     kb[0] += 100.0
     pb[0] = 0
 
-    paged = attn.attend_paged(jnp.asarray(q), jnp.asarray(kb),
-                              jnp.asarray(vb), jnp.asarray(pb),
-                              jnp.asarray(table), idx_map,
+    paged = attn.attend_paged(jnp.asarray(q), jnp.asarray(kb[None]),
+                              jnp.asarray(vb[None]), jnp.asarray(pb),
+                              jnp.asarray(table), idx_map, layer=0,
                               q_position=q_position)
     np.testing.assert_array_equal(np.asarray(dense), np.asarray(paged))
 

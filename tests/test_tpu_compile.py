@@ -130,3 +130,40 @@ def test_prefill_chunk_compiles_for_v5e(qwen_2_layers, one_chip,
         _i32(one_chip), _i32(one_chip))
     assert lowered.out_info[0].shape == (1, cfg.vocab)
     lowered.compile()
+
+
+@pytest.fixture(scope="module")
+def deepseek_2_layers(one_chip):
+    """deepseek-v2-lite at published widths, its leading dense layer and
+    one expert layer, holding 8 of the 64 routed experts."""
+    from repro.configs import get_config
+    from repro.distributed.sharding import split_tree
+    from repro.models import build_model
+    base = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(base, n_layers=2, moe=dataclasses.replace(
+        base.moe, n_held=8))
+    model = build_model(cfg)
+    params = jax.eval_shape(lambda k: split_tree(model.init(k))[0],
+                            jax.random.PRNGKey(0))
+    return cfg, model, _on(one_chip, params)
+
+
+def test_latent_paged_steps_compile_for_v5e(deepseek_2_layers, one_chip,
+                                            no_persistent_cache):
+    """Absorbed decode at batch 64 over 1,088-block views, and a 512-row
+    chunk, over a latent arena of one 576-wide row a token a layer, held
+    640 wide."""
+    cfg, model, params = deepseek_2_layers
+    paged = _paged(cfg, one_chip, n_blocks=2048)
+    assert paged.k.shape == (2, 2048, 16, 1, 640) and paged.v.shape[-1] == 0
+    decode = jax.jit(model.decode_paged, donate_argnums=(1,)).lower(
+        params, paged, _i32(one_chip, 64, 1), _i32(one_chip, 64, 1088),
+        _i32(one_chip, 64))
+    assert decode.out_info[0].shape == (64, cfg.vocab)
+    assert decode.out_info[1].picks.shape == (1, 64)
+    decode.compile()
+    chunk = jax.jit(model.prefill_chunk, donate_argnums=(1,)).lower(
+        params, paged, _i32(one_chip, 1, 512), _i32(one_chip, 1, 1088),
+        _i32(one_chip), _i32(one_chip))
+    assert chunk.out_info[0].shape == (1, cfg.vocab)
+    chunk.compile()
