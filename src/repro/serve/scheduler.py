@@ -56,7 +56,15 @@ per phase, never one per slot, each a child of the iteration's:
                           ``prefill``, ``chunk`` or ``decode``
   serve.sample            sampling at temperature > 0; ``active``
   serve.readback          tokens to the host, appends, finished requests
-                          freed; ``n_tokens``, ``finished``
+                          freed; ``n_tokens``, ``finished``, ``transfers``
+                          (device-to-host copies made in it: one per
+                          dispatch, whatever the batch)
+
+A dispatch's tokens reach the host in one copy: the scheduler starts it
+with ``copy_to_host_async`` as soon as the dispatch is made, waits for
+the device, then converts the whole array once.  No device array is
+indexed per slot (a ``jax.Array`` index is a dispatch and a sync of its
+own).
 
 For a model with experts, each decode step and prefill chunk also returns
 how many of its tokens each expert layer routed to each expert.  While the
@@ -429,6 +437,10 @@ class ContinuousScheduler(_SchedulerBase):
             logits, greedy, self.cache.state, picks = self._decode(
                 self.params, self.cache.state, jnp.asarray(tokens),
                 tables, jnp.asarray(slot_pos))
+            if temperature <= 0:
+                # runs as soon as the step ends: the readback then finds
+                # the tokens already on the host
+                greedy.copy_to_host_async()
             self._note_picks(picks, self.batch, ds)
         with tracer.span("serve.device_wait", on="decode"):
             toks = jax.block_until_ready(greedy if temperature <= 0
@@ -445,12 +457,14 @@ class ContinuousScheduler(_SchedulerBase):
                     toks[i] = int(jax.block_until_ready(sample(
                         logits[i:i + 1], step_key, temperature))[0])
         dec_h.observe((time.perf_counter() - t0) * 1e3)
-        with tracer.span("serve.readback", n_tokens=len(active)) as rb:
+        with tracer.span("serve.readback", n_tokens=len(active),
+                         transfers=int(temperature <= 0)) as rb:
+            host = np.asarray(toks).tolist()
             finished = 0
             for i in active:
                 s = slots[i]
                 s.pos += 1
-                s.req.out_tokens.append(int(toks[i]))
+                s.req.out_tokens.append(host[i])
                 _keep_logits(s.req, logits, i)
                 if len(s.req.out_tokens) >= s.target:
                     finish(i)
@@ -527,10 +541,13 @@ class ContinuousScheduler(_SchedulerBase):
                             bucket)(self.params, self._prefill_batch(r.prompt),
                                     self.cache.state,
                                     jnp.asarray(ids, jnp.int32))
+                        if temperature <= 0:
+                            greedy.copy_to_host_async()
                     first = self._first_token("prefill", logits, greedy,
                                               r.uid, temperature, base_key)
-                    with tracer.span("serve.readback", n_tokens=1) as rb:
-                        tok = int(first[0])
+                    with tracer.span("serve.readback", n_tokens=1,
+                                     transfers=1) as rb:
+                        tok = int(np.asarray(first)[0])
                         r.ttft_ms = _since_ms(arrive_wall[r.uid])
                         ttft_h.observe(r.ttft_ms)
                         r.out_tokens.append(tok)
@@ -690,6 +707,8 @@ class ContinuousScheduler(_SchedulerBase):
                             jnp.int32(start), jnp.int32(n))
                         self._note_picks(picks, width, pc)
                         s.filled = start + n
+                        if s.filled >= s.plen and temperature <= 0:
+                            greedy.copy_to_host_async()
                         # register incrementally: rows in completed
                         # absolute chunks are final (later chunks never
                         # rewrite them), so a prompt arriving mid-prefill
@@ -705,8 +724,9 @@ class ContinuousScheduler(_SchedulerBase):
                         first = self._first_token("chunk", logits, greedy,
                                                   r.uid, temperature,
                                                   base_key)
-                        with tracer.span("serve.readback", n_tokens=1) as rb:
-                            tok = int(first[0])
+                        with tracer.span("serve.readback", n_tokens=1,
+                                         transfers=1) as rb:
+                            tok = int(np.asarray(first)[0])
                             r.ttft_ms = _since_ms(arrive_wall[r.uid])
                             ttft_h.observe(r.ttft_ms)
                             r.out_tokens.append(tok)
@@ -812,9 +832,10 @@ class CohortScheduler(_SchedulerBase):
                     r.ttft_ms = (t_first - arrive_wall[r.uid]) * 1e3
                     ttft_h.observe(r.ttft_ms)
                 for step in range(max_steps):
+                    host = np.asarray(toks)[:, 0].tolist()  # one copy a step
                     for i, r in enumerate(active):
                         if not r.done and len(r.out_tokens) < r.max_new:
-                            r.out_tokens.append(int(toks[i, 0]))
+                            r.out_tokens.append(host[i])
                         elif not r.done:
                             r.done = True
                     if all(r.done or len(r.out_tokens) >= r.max_new

@@ -671,10 +671,66 @@ def test_scheduler_span_tree_under_a_profiler_session(served, chunk,
     assert sum(s.attrs["n_tokens"] for s in readback) == \
         sum(len(v) for v in out.values())
     assert sum(s.attrs["finished"] for s in readback) == len(reqs)
+    assert all(s.attrs["transfers"] in (0, 1) for s in readback)
+    if temperature == 0:
+        assert all(s.attrs["transfers"] == 1 for s in readback)
 
     again = _reqs(cfg, lens=(21, 7, 40, 12), max_new=(3, 5, 2, 4), seed=5)
     sched.run(again, temperature=temperature)
     assert tr.spans() == []
+
+
+@pytest.mark.parametrize("chunk", [None, 16])
+def test_readback_is_one_copy_per_dispatch(served, chunk, monkeypatch):
+    """A dispatch's tokens reach the host in one copy: the scheduler
+    indexes no device array while it serves, every ``serve.readback``
+    span (decode steps and first tokens alike) records one transfer, and
+    the tokens are the solo oracle's."""
+    import sys
+    from jax._src.array import ArrayImpl
+    from repro.obs.trace import tracer
+    from repro.serve import scheduler
+    cfg, _, params = served
+    reqs = lambda: [Request(uid=r.uid, prompt=r.prompt, max_new=r.max_new,
+                            arrival=float(r.uid))
+                    for r in _reqs(cfg, lens=(21, 7, 12, 9),
+                                   max_new=(3, 5, 2, 4), seed=17)]
+
+    def make(batch):
+        return ContinuousScheduler(cfg, params, batch=batch, max_seq=64,
+                                   block_len=8, chunk_tokens=chunk,
+                                   prefix_cache=chunk is not None)
+
+    oracle = {}
+    for r in reqs():
+        r.arrival = 0.0
+        oracle.update(make(1).run([r]))
+
+    indexed = []
+    getitem = ArrayImpl.__getitem__
+
+    def counting(self, idx):
+        if sys._getframe(1).f_code.co_filename == scheduler.__file__:
+            indexed.append(idx)
+        return getitem(self, idx)
+
+    monkeypatch.setattr(ArrayImpl, "__getitem__", counting)
+    tr = tracer()
+    tr.clear()
+    tr.enable()
+    try:
+        out = make(2).run(reqs())
+    finally:
+        tr.disable()
+    spans = tr.spans()
+    tr.clear()
+
+    assert indexed == []
+    readback = [s for s in spans if s.name == "serve.readback"]
+    n_decode = sum(s.name == "serve.decode_step" for s in spans)
+    assert len(readback) == n_decode + len(oracle)
+    assert all(s.attrs["transfers"] == 1 for s in readback)
+    assert out == oracle
 
 
 def test_prefix_sharing_hits_and_is_bit_identical(served):
