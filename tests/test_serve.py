@@ -733,6 +733,125 @@ def test_readback_is_one_copy_per_dispatch(served, chunk, monkeypatch):
     assert out == oracle
 
 
+def _event_order_requests(cfg):
+    """Four requests for two slots: 0 and 1 share a 16-token head, 1
+    falls due while 0 prefills, 3 arrives after the batch has drained."""
+    rng = np.random.default_rng(31)
+    head = rng.integers(0, cfg.vocab, (16,)).astype(np.int32)
+    tail = lambda n: rng.integers(0, cfg.vocab, (n,)).astype(np.int32)
+    prompts = (np.concatenate([head, tail(4)]), np.concatenate([head, tail(6)]),
+               tail(9), tail(12))
+    return [Request(uid=i, prompt=p, max_new=m, arrival=a)
+            for i, (p, m, a) in enumerate(zip(prompts, (3, 2, 2, 2),
+                                              (0.0, 1.0, 2.0, 12.0)))]
+
+
+def _event_order(cfg, params, chunk):
+    """Serve the event-order trace with the tracer on; returns the outputs
+    and, for each ``serve.step`` in order, its occupancy attributes and its
+    children in start order as (name, uid, start, n_tokens, on)."""
+    from repro.obs.trace import tracer
+    sched = ContinuousScheduler(cfg, params, batch=2, max_seq=64,
+                                block_len=8, prefix_cache=chunk is not None)
+    tr = tracer()
+    tr.clear()
+    tr.enable()
+    try:
+        out = sched.run(_event_order_requests(cfg), max_steps=8)
+    finally:
+        tr.disable()
+    spans = sorted(tr.spans(), key=lambda s: s.t0_us)
+    tr.clear()
+    steps = []
+    for st in (s for s in spans if s.name == "serve.step"):
+        kids = [(s.name[len("serve."):],) + tuple(
+                    s.attrs.get(k) for k in ("uid", "start", "n_tokens", "on"))
+                for s in spans if s.parent_id == st.span_id]
+        steps.append(((st.attrs["active"], st.attrs["queued"],
+                       st.attrs["prefilling"]), kids))
+    return out, steps
+
+
+#: ``_event_order``'s iterations as the scheduler ran them: ((active,
+#: queued, prefilling), children) a ``serve.step``
+EVENT_ORDER = {None: [
+    ((0, 1, 0), [("admit", 0, None, None, None),
+                 ("prefill", 0, 0, 20, None),
+                 ("device_wait", None, None, None, "prefill"),
+                 ("readback", None, None, 1, None),
+                 ("admit", 1, None, None, None),
+                 ("prefill", 1, 0, 22, None),
+                 ("device_wait", None, None, None, "prefill"),
+                 ("readback", None, None, 1, None),
+                 ("decode_step", None, None, None, None),
+                 ("device_wait", None, None, None, "decode"),
+                 ("readback", None, None, 2, None)]),
+    ((1, 1, 0), [("admit", 2, None, None, None),
+                 ("prefill", 2, 0, 9, None),
+                 ("device_wait", None, None, None, "prefill"),
+                 ("readback", None, None, 1, None),
+                 ("decode_step", None, None, None, None),
+                 ("device_wait", None, None, None, "decode"),
+                 ("readback", None, None, 2, None)]),
+    ((0, 0, 0), []),
+    ((0, 1, 0), [("admit", 3, None, None, None),
+                 ("prefill", 3, 0, 12, None),
+                 ("device_wait", None, None, None, "prefill"),
+                 ("readback", None, None, 1, None),
+                 ("decode_step", None, None, None, None),
+                 ("device_wait", None, None, None, "decode"),
+                 ("readback", None, None, 1, None)]),
+], 8: [
+    ((0, 1, 0), [("admit", 0, None, None, None),
+                 ("prefill_chunk", 0, 0, 8, None)]),
+    ((0, 1, 1), [("admit", 1, None, None, None),
+                 ("prefill_chunk", 0, 8, 8, None)]),
+    ((0, 1, 2), [("prefill_chunk", 0, 16, 4, None),
+                 ("device_wait", None, None, None, "chunk"),
+                 ("readback", None, None, 1, None),
+                 ("decode_step", None, None, None, None),
+                 ("device_wait", None, None, None, "decode"),
+                 ("readback", None, None, 1, None)]),
+    ((1, 1, 1), [("prefill_chunk", 1, 16, 6, None),
+                 ("device_wait", None, None, None, "chunk"),
+                 ("readback", None, None, 1, None),
+                 ("decode_step", None, None, None, None),
+                 ("device_wait", None, None, None, "decode"),
+                 ("readback", None, None, 2, None)]),
+    ((0, 1, 0), [("admit", 2, None, None, None),
+                 ("prefill_chunk", 2, 0, 8, None)]),
+    ((0, 0, 1), [("prefill_chunk", 2, 8, 1, None),
+                 ("device_wait", None, None, None, "chunk"),
+                 ("readback", None, None, 1, None),
+                 ("decode_step", None, None, None, None),
+                 ("device_wait", None, None, None, "decode"),
+                 ("readback", None, None, 1, None)]),
+    ((0, 0, 0), []),
+    ((0, 1, 0), [("admit", 3, None, None, None),
+                 ("prefill_chunk", 3, 0, 8, None)]),
+    ((0, 0, 1), [("prefill_chunk", 3, 8, 4, None),
+                 ("device_wait", None, None, None, "chunk"),
+                 ("readback", None, None, 1, None),
+                 ("decode_step", None, None, None, None),
+                 ("device_wait", None, None, None, "decode"),
+                 ("readback", None, None, 1, None)]),
+]}
+#: both modes emit the same tokens here
+EVENT_OUTPUTS = {0: [12, 125, 116], 1: [120, 19], 2: [35, 26], 3: [11, 11]}
+
+
+@pytest.mark.parametrize("chunk", [None, 8], ids=["monolithic", "chunked"])
+def test_scheduler_event_order_is_pinned(served, chunk):
+    """The order of admissions, prefill dispatches, first-token waits,
+    decode steps and readbacks within each iteration, as the virtual clock
+    advances and arrivals fall due, is exactly the recorded one, in both
+    prefill modes (chunked with the prefix cache), and so are the tokens."""
+    cfg, _, params = served
+    out, steps = _event_order(cfg, params, chunk)
+    assert out == EVENT_OUTPUTS
+    assert steps == EVENT_ORDER[chunk]
+
+
 def test_prefix_sharing_hits_and_is_bit_identical(served):
     """Requests sharing a 32-token prefix: the prefix cache must serve
     later prefills from shared blocks (hit_tokens > 0) without changing
