@@ -145,8 +145,8 @@ def main(argv=None):
     ap.add_argument("--prefix-cache", action="store_true",
                     help="content-address full KV blocks and share cached "
                          "prompt prefixes across requests (implies chunked "
-                         "prefill at 4 * --block-len unless --chunk-tokens "
-                         "is given)")
+                         "prefill at --block-len unless --chunk-tokens is "
+                         "given)")
     ap.add_argument("--prefix-len", type=int, default=0,
                     help="shared-prefix traces: give arrival-trace prompts "
                          "a common random prefix of this many tokens")

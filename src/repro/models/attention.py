@@ -21,7 +21,7 @@ the output, so its query heads attend the latent rows themselves.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,34 +75,6 @@ def qkv_project(p, x, cfg: ArchConfig, positions, compute_dtype):
         q = rope(q, positions, cfg.attn.rope_theta)
         k = rope(k, positions, cfg.attn.rope_theta)
     return q, k, v
-
-
-class DecodeCache(NamedTuple):
-    """Per-layer-stacked KV cache.  ``k``/``v``: (L, B, W, KV, hd); ``pos``:
-    (L, B, W) absolute position of each slot (-1 = empty).  W is the full
-    sequence budget, or the window size for sliding-window layers."""
-    k: jax.Array
-    v: jax.Array
-    pos: jax.Array
-
-
-def init_cache(cfg: ArchConfig, batch: int, budget: int,
-               dtype=jnp.bfloat16, n_layers: Optional[int] = None):
-    nkv, hd = cfg.n_kv_heads, cfg.head_dim_
-    L = n_layers if n_layers is not None else cfg.n_layers
-    w = min(budget, cfg.attn.window) if cfg.attn.window > 0 else budget
-    shape = (L, batch, w, nkv, hd)
-    return DecodeCache(
-        k=jnp.zeros(shape, dtype),
-        v=jnp.zeros(shape, dtype),
-        pos=jnp.full((L, batch, w), -1, jnp.int32),
-    )
-
-
-def cache_spec_axes():
-    return {"k": (None, "batch", None, "kv", None),
-            "v": (None, "batch", None, "kv", None),
-            "pos": (None, "batch", None)}
 
 
 def update_cache_layer(k_layer, v_layer, pos_layer, k_new, v_new, positions):

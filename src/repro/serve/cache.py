@@ -144,7 +144,6 @@ class PagedKVCache:
         self._slot_blocks: List[List[int]] = [[] for _ in range(batch)]
         # blocks promised to admitted slots but not yet allocated
         self._slot_reserved = np.zeros((batch,), np.int64)
-        self._write_fns = {}            # n_prefill_blocks -> jitted scatter
         # device copy of self.tables, re-uploaded only when tables change
         # (most decode steps allocate nothing, so the upload is elided)
         self._dev_tables: Optional[jax.Array] = None
@@ -538,22 +537,3 @@ class PagedKVCache:
             self._dev_tables = jnp.asarray(self.tables)
         return self._dev_tables
 
-    def write_prefill(self, slot: int, dense_state, pads: int = 0) -> None:
-        """Copy a dense prefill cache (``models.transformer.State`` for a
-        B=1 request, budget == whole blocks) into the slot's blocks.
-        ``pads`` left-pad rows get their positions forced to -1."""
-        ids = self._slot_blocks[slot]
-        bucket = dense_state.k.shape[2]
-        if bucket != len(ids) * self.block_len:
-            raise ValueError(f"bucket {bucket} != {len(ids)} blocks of "
-                             f"{self.block_len}")
-        pos = dense_state.kpos[0, 0]
-        if pads:
-            pos = jnp.where(jnp.arange(bucket) < pads, -1, pos)
-        nb = len(ids)
-        fn = self._write_fns.get(nb)
-        if fn is None:
-            fn = self._write_fns[nb] = jax.jit(scatter_prefill,
-                                               donate_argnums=(0,))
-        self.state = fn(self.state, dense_state.k, dense_state.v, pos,
-                        jnp.asarray(ids, jnp.int32))

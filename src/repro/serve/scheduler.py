@@ -7,13 +7,16 @@ the very next step, so short requests never hold the batch hostage the
 way cohort scheduling does — occupancy stays near 1 under mixed-length
 traffic, which is where the tokens/s win comes from.
 
-Prefill runs one request at a time at ``B=1`` with the request's *exact*
-token length (no left padding), then scatters the dense cache into the
-slot's arena blocks.  Exact-length prefill makes every request's greedy
-output bit-identical to a one-request-at-a-time oracle regardless of
+One loop serves both prefill policies.  Each iteration admits arrived
+requests into free slots, does prefill work, then runs one decode step
+over the slots past prefill.  Monolithic prefill runs each admission's
+whole prompt at ``B=1`` with its *exact* token length (no left padding)
+and scatters the dense cache into the slot's arena blocks; chunked
+prefill (``chunk_tokens``) runs one chunk of the oldest mid-prefill slot
+an iteration.  Either way every request's greedy output is bit-identical
+to a one-request-at-a-time oracle of the same policy regardless of
 arrival order, batch size, or what else shares the batch — the property
-the serving tests pin.  The KV budget is bucketed to the next power of
-two (whole blocks), so the *decode* step compiles exactly once.
+the serving tests pin.  The decode step compiles exactly once.
 
 ``CohortScheduler`` is the old ``ServingLoop`` body behind the same
 interface: take up to ``batch`` arrived requests, left-pad, prefill,
@@ -217,17 +220,16 @@ class _Slot:
     __slots__ = ("req", "pos", "target", "t_arrive", "plen", "filled",
                  "prefilling", "started")
 
-    def __init__(self, req: Request, pos: int, target: int, t_arrive: float,
-                 plen: int = 0, filled: int = 0, prefilling: bool = False):
+    def __init__(self, req: Request, plen: int, target: int,
+                 t_arrive: float, filled: int):
         self.req = req
-        self.pos = pos          # next cache row this slot writes
+        self.plen = plen        # prompt rows this slot must prefill
+        self.pos = plen         # next cache row this slot writes
         self.target = target    # tokens to emit (min(max_new, max_steps))
         self.t_arrive = t_arrive
-        # chunked-prefill progress (unused by the monolithic path)
-        self.plen = plen        # prompt rows this slot must prefill
-        self.filled = filled    # prompt rows written so far (chunk-aligned)
-        self.prefilling = prefilling
-        self.started = False    # True once the first chunk dispatched
+        self.filled = filled    # prompt rows cached so far (chunk-aligned)
+        self.prefilling = True  # until its first token is read back
+        self.started = False    # chunked: True once the first chunk ran
 
 
 class ContinuousScheduler(_SchedulerBase):
@@ -235,8 +237,7 @@ class ContinuousScheduler(_SchedulerBase):
 
     ``total_tokens`` sets the arena budget (default: enough for every
     slot to hold ``max_seq`` rows); ``max_seq`` bounds one request's
-    prompt + generation; ``max_prefills_per_step`` caps how many arrivals
-    are admitted between decode steps (default: the batch size).
+    prompt + generation.
 
     ``chunk_tokens`` switches prefill from one monolithic exact-length
     dispatch to fixed-size chunks interleaved with decode steps (one
@@ -259,7 +260,6 @@ class ContinuousScheduler(_SchedulerBase):
                  metrics: Optional[obs_metrics.Registry] = None,
                  block_len: int = 16, max_seq: int = 1024,
                  total_tokens: Optional[int] = None,
-                 max_prefills_per_step: Optional[int] = None,
                  chunk_tokens: Optional[int] = None,
                  prefix_cache: bool = False):
         super().__init__(cfg, params, batch=batch, rules=rules, seed=seed,
@@ -291,8 +291,6 @@ class ContinuousScheduler(_SchedulerBase):
         self.cache = PagedKVCache(cfg, batch, total_tokens=total_tokens,
                                   max_seq=max_seq, block_len=block_len,
                                   prefix_cache=self.prefix_cache)
-        self.max_prefills_per_step = (batch if max_prefills_per_step is None
-                                      else max_prefills_per_step)
         self._prefill_fns = {}          # KV bucket -> jitted prefill
         self._chunk_fns = {}            # pow2 chunk width -> jitted chunk
         # vlm prompts prepend n_patches rows to the cache during prefill
@@ -395,28 +393,158 @@ class ContinuousScheduler(_SchedulerBase):
 
     # -- main loop ----------------------------------------------------------
 
-    def _first_token(self, on: str, logits, greedy, uid: int,
-                     temperature: float, base_key):
-        """Wait for a prefill's first token; returns the (1,) device array
-        that holds it."""
+    def _lifetime(self, plen: int, target: int) -> int:
+        """Rows to reserve for a request: prompt plus output, and at least
+        the monolithic prefill's bucket, which it writes whole."""
+        if self.chunk_tokens is None:
+            return max(self._bucket(plen), plen + target)
+        return plen + target
+
+    def _admit(self, run: "_Run", i: int, r: Request, plen: int,
+               target: int, lifetime: int) -> _Slot:
+        """Give slot ``i`` to ``r``: reserve its lifetime's arena blocks,
+        allocating the prefill bucket's now (monolithic) or mapping the
+        longest cached prefix (chunked).  The match is capped to whole
+        chunks strictly below the prompt's last row, so at least one chunk
+        (and the first token's logits) is always computed live with the
+        same dispatch shape the solo oracle uses."""
+        T = self.chunk_tokens
+        with get_tracer().span("serve.admit", uid=r.uid,
+                               prompt_len=len(r.prompt),
+                               waited_ms=_since_ms(run.arrive_wall[r.uid])
+                               ) as adm:
+            if T is None:
+                self.cache.admit(i, self._bucket(plen), lifetime)
+                matched = 0
+            else:
+                matched = self.cache.admit_shared(
+                    i, np.asarray(r.prompt, np.int32).reshape(-1), lifetime,
+                    max_match_rows=((plen - 1) // T) * T, granule_rows=T)
+            if adm is not None:
+                adm.attrs["matched"] = matched
+        if T is not None:
+            run.hit_c.inc(matched)
+            run.miss_c.inc(plen - matched)
+        return _Slot(r, plen, target, run.arrive_wall[r.uid], matched)
+
+    def _prefill(self, run: "_Run", i: int) -> None:
+        """One prefill dispatch for slot ``i``: its whole prompt, or its
+        next chunk.  Once the prompt is cached its first token is read
+        back.  Either way the virtual clock then advances one step and the
+        arrivals it makes due join the queue."""
+        s = run.slots[i]
+        r = s.req
+        if self.chunk_tokens is None:
+            bucket = self._bucket(s.plen)
+            with get_tracer().span("serve.prefill", uid=r.uid, start=0,
+                                   n_tokens=len(r.prompt)):
+                logits, greedy, self.cache.state = self._get_prefill(bucket)(
+                    self.params, self._prefill_batch(r.prompt),
+                    self.cache.state,
+                    jnp.asarray(self.cache.tables[i, :bucket
+                                                  // self.block_len]))
+                s.filled = s.plen
+                if run.temperature <= 0:
+                    greedy.copy_to_host_async()
+        else:
+            logits, greedy = self._prefill_chunk(run, i)
+        if s.filled >= s.plen:
+            self._first_token(run, i, logits, greedy)
+        run.clock += 1.0
+        run.drain_arrivals()
+
+    def _prefill_chunk(self, run: "_Run", i: int):
+        """Dispatch slot ``i``'s next prefill chunk; returns its logits and
+        greedy pick."""
+        s = run.slots[i]
+        T = self.chunk_tokens
+        prompt = np.asarray(s.req.prompt, np.int32).reshape(-1)
+        with get_tracer().span("serve.prefill_chunk", uid=s.req.uid) as pc:
+            if not s.started:
+                # last chance to share: a producer that was still
+                # mid-prefill at our admission has registered its completed
+                # chunks by now — graft them on while this slot has written
+                # nothing
+                grown = self.cache.extend_match(
+                    i, prompt, max_match_rows=((s.plen - 1) // T) * T,
+                    granule_rows=T)
+                if grown > s.filled:
+                    run.hit_c.inc(grown - s.filled)
+                    run.miss_c.inc(s.filled - grown)
+                    s.filled = grown
+                s.started = True
+            start = s.filled
+            n = min(T, s.plen - start)
+            if pc is not None:
+                pc.attrs.update(start=start, n_tokens=n)
+            width = next_pow2(n)
+            self.cache.extend_to(i, start + n)
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :n] = prompt[start:start + n]
+            logits, greedy, self.cache.state, picks = self._get_chunk(width)(
+                self.params, self.cache.state, jnp.asarray(toks),
+                jnp.asarray(self.cache.tables[i:i + 1]),
+                jnp.int32(start), jnp.int32(n))
+            self._note_picks(picks, width, pc)
+            s.filled = start + n
+            if s.filled >= s.plen and run.temperature <= 0:
+                greedy.copy_to_host_async()
+            # register incrementally: rows in completed absolute chunks are
+            # final (later chunks never rewrite them), so a prompt arriving
+            # mid-prefill can already share them.  Only FULL aligned chunks
+            # qualify — rows of a final partial chunk ran at a different
+            # dispatch width, so their low bits are not what a sharing
+            # consumer's oracle would produce.
+            self.cache.register_prefix(i, prompt, (s.filled // T) * T)
+        return logits, greedy
+
+    def _first_token(self, run: "_Run", i: int, logits, greedy) -> None:
+        """Wait for slot ``i``'s first token, read it back and start the
+        slot decoding (or finish it, if that was its last token)."""
         tracer = get_tracer()
-        if temperature > 0:
+        s = run.slots[i]
+        r = s.req
+        if run.temperature > 0:
             with tracer.span("serve.sample", active=1):
                 greedy = sample(logits, jax.random.fold_in(
-                    _request_key(base_key, uid), 0), temperature)
-        with tracer.span("serve.device_wait", on=on):
+                    _request_key(run.base_key, r.uid), 0), run.temperature)
+        with tracer.span("serve.device_wait", on="prefill"
+                         if self.chunk_tokens is None else "chunk"):
             first = jax.block_until_ready(greedy)
         self._count_picks()
-        return first
+        with tracer.span("serve.readback", n_tokens=1, transfers=1) as rb:
+            tok = int(np.asarray(first)[0])
+            r.ttft_ms = _since_ms(s.t_arrive)
+            run.ttft_h.observe(r.ttft_ms)
+            r.out_tokens.append(tok)
+            _keep_logits(r, logits, 0)
+            s.prefilling = False
+            done = len(r.out_tokens) >= s.target
+            if done:
+                self._finish(run, i)
+            if rb is not None:
+                rb.attrs["finished"] = int(done)
 
-    def _decode_and_read(self, slots, active, temperature, base_key,
-                         finish, dec_h, occ_h) -> None:
+    def _finish(self, run: "_Run", i: int) -> None:
+        """Slot ``i``'s request is done: record it and free the slot."""
+        s = run.slots[i]
+        s.req.done = True
+        s.req.total_ms = _since_ms(s.t_arrive)
+        run.results[s.req.uid] = s.req.out_tokens
+        run.req_c.inc()
+        run.tok_c.inc(len(s.req.out_tokens))
+        self.cache.free_slot(i)
+        run.slots[i] = None
+
+    def _decode_and_read(self, run: "_Run", active: List[int]) -> None:
         """One decode step over the ``active`` slots, then their tokens to
         the host.  Mid-prefill slots are masked out of the block table:
         their decode rows are inactive (slot_pos 0) and must write the
-        scratch block, not the real block their table maps at row 0."""
+        scratch block, not the real (possibly shared) block their table
+        maps at row 0."""
         tracer = get_tracer()
-        occ_h.observe(len(active) / self.batch)
+        slots, temperature = run.slots, run.temperature
+        run.occ_h.observe(len(active) / self.batch)
         with tracer.span("serve.decode_step", active=len(active)) as ds:
             tokens = np.zeros((self.batch, 1), np.int32)
             slot_pos = np.zeros((self.batch,), np.int32)
@@ -452,11 +580,11 @@ class ContinuousScheduler(_SchedulerBase):
                 for i in active:
                     s = slots[i]
                     step_key = jax.random.fold_in(
-                        _request_key(base_key, s.req.uid),
+                        _request_key(run.base_key, s.req.uid),
                         len(s.req.out_tokens))
                     toks[i] = int(jax.block_until_ready(sample(
                         logits[i:i + 1], step_key, temperature))[0])
-        dec_h.observe((time.perf_counter() - t0) * 1e3)
+        run.dec_h.observe((time.perf_counter() - t0) * 1e3)
         with tracer.span("serve.readback", n_tokens=len(active),
                          transfers=int(temperature <= 0)) as rb:
             host = np.asarray(toks).tolist()
@@ -467,61 +595,38 @@ class ContinuousScheduler(_SchedulerBase):
                 s.req.out_tokens.append(host[i])
                 _keep_logits(s.req, logits, i)
                 if len(s.req.out_tokens) >= s.target:
-                    finish(i)
+                    self._finish(run, i)
                     finished += 1
             if rb is not None:
                 rb.attrs["finished"] = finished
 
     def run(self, requests: List[Request], temperature: float = 0.0,
             max_steps: int = 64) -> Dict[int, List[int]]:
-        if self.chunk_tokens is not None:
-            return self._run_chunked(requests, temperature, max_steps)
+        """Serve ``requests``; returns each one's output tokens by uid.
+        Each iteration admits arrived requests into free slots, does the
+        prefill work (monolithic: every admission's whole prompt; chunked:
+        one chunk for the oldest mid-prefill slot), then one decode step
+        over the slots past prefill."""
         tracer = get_tracer()
-        ttft_h, dec_h, occ_h, req_c, tok_c = self._metric_handles()
-        base_key = jax.random.PRNGKey(self.seed)
-
-        pending = deque(sorted(requests, key=lambda r: (r.arrival, r.uid)))
-        queue: deque = deque()          # arrived, waiting for a slot
-        arrive_wall: Dict[int, float] = {}
-        slots: List[Optional[_Slot]] = [None] * self.batch
-        results: Dict[int, List[int]] = {}
-        clock = 0.0                     # virtual steps
-
-        def finish(i: int):
-            s = slots[i]
-            s.req.done = True
-            s.req.total_ms = (time.perf_counter() - s.t_arrive) * 1e3
-            results[s.req.uid] = s.req.out_tokens
-            req_c.inc()
-            tok_c.inc(len(s.req.out_tokens))
-            self.cache.free_slot(i)
-            slots[i] = None
-
-        def drain_arrivals():
-            # pending -> queue once the virtual clock reaches them
-            now = time.perf_counter()
-            while pending and pending[0].arrival <= clock:
-                r = pending.popleft()
-                queue.append(r)
-                arrive_wall[r.uid] = now
-
+        run = _Run(self, requests, temperature)
+        slots, queue, pending = run.slots, run.queue, run.pending
+        chunked = self.chunk_tokens is not None
         while pending or queue or any(s is not None for s in slots):
             with tracer.span("serve.step") as step:
-                drain_arrivals()
+                run.drain_arrivals()
                 if step is not None:
                     step.attrs.update(_occupancy(slots), queued=len(queue))
 
                 # admission: refill free slots while the arena has room
-                n_pref = 0
-                while queue and n_pref < self.max_prefills_per_step:
+                n_adm = 0
+                while queue and n_adm < self.batch:
                     free = [i for i, s in enumerate(slots) if s is None]
                     if not free:
                         break
                     r = queue[0]
                     target = min(r.max_new, max_steps)
                     plen = len(r.prompt) + self._extra_rows
-                    bucket = self._bucket(plen)
-                    lifetime = max(bucket, plen + target)
+                    lifetime = self._lifetime(plen, target)
                     if not self.cache.can_admit(lifetime):
                         if not any(s is not None for s in slots):
                             raise RuntimeError(
@@ -531,213 +636,17 @@ class ContinuousScheduler(_SchedulerBase):
                         break           # wait for a slot to free blocks
                     queue.popleft()
                     i = free[0]
-                    with tracer.span("serve.admit", uid=r.uid,
-                                     prompt_len=len(r.prompt), matched=0,
-                                     waited_ms=_since_ms(arrive_wall[r.uid])):
-                        ids = self.cache.admit(i, bucket, lifetime)
-                    with tracer.span("serve.prefill", uid=r.uid, start=0,
-                                     n_tokens=len(r.prompt)):
-                        logits, greedy, self.cache.state = self._get_prefill(
-                            bucket)(self.params, self._prefill_batch(r.prompt),
-                                    self.cache.state,
-                                    jnp.asarray(ids, jnp.int32))
-                        if temperature <= 0:
-                            greedy.copy_to_host_async()
-                    first = self._first_token("prefill", logits, greedy,
-                                              r.uid, temperature, base_key)
-                    with tracer.span("serve.readback", n_tokens=1,
-                                     transfers=1) as rb:
-                        tok = int(np.asarray(first)[0])
-                        r.ttft_ms = _since_ms(arrive_wall[r.uid])
-                        ttft_h.observe(r.ttft_ms)
-                        r.out_tokens.append(tok)
-                        _keep_logits(r, logits, 0)
-                        slots[i] = _Slot(r, pos=plen, target=target,
-                                         t_arrive=arrive_wall[r.uid])
-                        done = len(r.out_tokens) >= target
-                        if done:
-                            finish(i)
-                        if rb is not None:
-                            rb.attrs["finished"] = int(done)
-                    n_pref += 1
-                    clock += 1.0
-                    drain_arrivals()
-
-                active = [i for i, s in enumerate(slots) if s is not None]
-                if not active:
-                    if pending:
-                        # idle: jump the virtual clock to the next arrival
-                        clock = max(clock, pending[0].arrival)
-                        continue
-                    if queue:
-                        continue        # admission will retry (or raise)
-                    break
-
-                # one decode step over every slot (inactive slots write the
-                # scratch block and their logits are discarded)
-                self._decode_and_read(slots, active, temperature, base_key,
-                                      finish, dec_h, occ_h)
-                clock += 1.0
-        return results
-
-    def _run_chunked(self, requests: List[Request], temperature: float,
-                     max_steps: int) -> Dict[int, List[int]]:
-        """Chunked-prefill loop: admission only reserves arena blocks
-        (and maps any shared prefix); each scheduler step then dispatches
-        one prefill chunk for the oldest mid-prefill slot, followed by
-        one decode step over the fully-prefilled slots.  Mid-prefill
-        slots are masked out of the decode dispatch's block table so the
-        inactive-row scratch write can never land in their (possibly
-        shared) blocks."""
-        tracer = get_tracer()
-        ttft_h, dec_h, occ_h, req_c, tok_c = self._metric_handles()
-        hit_c = self.metrics.counter("serve.prefix_hit_tokens")
-        miss_c = self.metrics.counter("serve.prefix_miss_tokens")
-        base_key = jax.random.PRNGKey(self.seed)
-        T = self.chunk_tokens
-
-        pending = deque(sorted(requests, key=lambda r: (r.arrival, r.uid)))
-        queue: deque = deque()          # arrived, waiting for a slot
-        arrive_wall: Dict[int, float] = {}
-        slots: List[Optional[_Slot]] = [None] * self.batch
-        results: Dict[int, List[int]] = {}
-        clock = 0.0                     # virtual steps
-
-        def finish(i: int):
-            s = slots[i]
-            s.req.done = True
-            s.req.total_ms = (time.perf_counter() - s.t_arrive) * 1e3
-            results[s.req.uid] = s.req.out_tokens
-            req_c.inc()
-            tok_c.inc(len(s.req.out_tokens))
-            self.cache.free_slot(i)
-            slots[i] = None
-
-        def drain_arrivals():
-            now = time.perf_counter()
-            while pending and pending[0].arrival <= clock:
-                r = pending.popleft()
-                queue.append(r)
-                arrive_wall[r.uid] = now
-
-        while pending or queue or any(s is not None for s in slots):
-            with tracer.span("serve.step") as step:
-                drain_arrivals()
-                if step is not None:
-                    step.attrs.update(_occupancy(slots), queued=len(queue))
-
-                # admission: reserve blocks + map shared prefix, no
-                # dispatch.  The match is capped to whole chunks strictly
-                # below the prompt's last row, so at least one chunk (and
-                # the first token's logits) is always computed live with
-                # the same dispatch shape the solo oracle uses.
-                n_adm = 0
-                while queue and n_adm < self.max_prefills_per_step:
-                    free = [i for i, s in enumerate(slots) if s is None]
-                    if not free:
-                        break
-                    r = queue[0]
-                    target = min(r.max_new, max_steps)
-                    plen = len(r.prompt)
-                    lifetime = plen + target
-                    if not self.cache.can_admit(lifetime):
-                        if not any(s is not None for s in slots):
-                            raise RuntimeError(
-                                f"request {r.uid} (lifetime {lifetime} "
-                                f"tokens) cannot fit the arena even when "
-                                f"idle")
-                        break           # wait for a slot to free blocks
-                    queue.popleft()
-                    i = free[0]
-                    with tracer.span("serve.admit", uid=r.uid,
-                                     prompt_len=plen,
-                                     waited_ms=_since_ms(arrive_wall[r.uid])
-                                     ) as adm:
-                        matched = self.cache.admit_shared(
-                            i, np.asarray(r.prompt, np.int32).reshape(-1),
-                            lifetime, max_match_rows=((plen - 1) // T) * T,
-                            granule_rows=T)
-                        if adm is not None:
-                            adm.attrs["matched"] = matched
-                    hit_c.inc(matched)
-                    miss_c.inc(plen - matched)
-                    slots[i] = _Slot(r, pos=plen, target=target,
-                                     t_arrive=arrive_wall[r.uid], plen=plen,
-                                     filled=matched, prefilling=True)
+                    slots[i] = self._admit(run, i, r, plen, target, lifetime)
                     n_adm += 1
+                    if not chunked:
+                        self._prefill(run, i)
 
-                # one prefill chunk for the oldest mid-prefill slot
-                pref = [i for i, s in enumerate(slots)
-                        if s is not None and s.prefilling]
-                if pref:
-                    i = min(pref, key=lambda j: (slots[j].req.arrival,
-                                                 slots[j].req.uid))
-                    s = slots[i]
-                    r = s.req
-                    prompt = np.asarray(r.prompt, np.int32).reshape(-1)
-                    with tracer.span("serve.prefill_chunk", uid=r.uid) as pc:
-                        if not s.started:
-                            # last chance to share: a producer that was
-                            # still mid-prefill at our admission has
-                            # registered its completed chunks by now —
-                            # graft them on while this slot has written
-                            # nothing
-                            grown = self.cache.extend_match(
-                                i, prompt,
-                                max_match_rows=((s.plen - 1) // T) * T,
-                                granule_rows=T)
-                            if grown > s.filled:
-                                hit_c.inc(grown - s.filled)
-                                miss_c.inc(s.filled - grown)
-                                s.filled = grown
-                            s.started = True
-                        start = s.filled
-                        n = min(T, s.plen - start)
-                        if pc is not None:
-                            pc.attrs.update(start=start, n_tokens=n)
-                        width = next_pow2(n)
-                        self.cache.extend_to(i, start + n)
-                        toks = np.zeros((1, width), np.int32)
-                        toks[0, :n] = prompt[start:start + n]
-                        (logits, greedy, self.cache.state,
-                         picks) = self._get_chunk(width)(
-                            self.params, self.cache.state,
-                            jnp.asarray(toks),
-                            jnp.asarray(self.cache.tables[i:i + 1]),
-                            jnp.int32(start), jnp.int32(n))
-                        self._note_picks(picks, width, pc)
-                        s.filled = start + n
-                        if s.filled >= s.plen and temperature <= 0:
-                            greedy.copy_to_host_async()
-                        # register incrementally: rows in completed
-                        # absolute chunks are final (later chunks never
-                        # rewrite them), so a prompt arriving mid-prefill
-                        # can already share them.  Only FULL aligned
-                        # chunks qualify — rows of a final partial chunk
-                        # ran at a different dispatch width, so their low
-                        # bits are not what a sharing consumer's oracle
-                        # would produce.
-                        self.cache.register_prefix(i, prompt,
-                                                   (s.filled // T) * T)
-                    clock += 1.0
-                    if s.filled >= s.plen:
-                        first = self._first_token("chunk", logits, greedy,
-                                                  r.uid, temperature,
-                                                  base_key)
-                        with tracer.span("serve.readback", n_tokens=1,
-                                         transfers=1) as rb:
-                            tok = int(np.asarray(first)[0])
-                            r.ttft_ms = _since_ms(arrive_wall[r.uid])
-                            ttft_h.observe(r.ttft_ms)
-                            r.out_tokens.append(tok)
-                            _keep_logits(r, logits, 0)
-                            s.prefilling = False
-                            done = len(r.out_tokens) >= s.target
-                            if done:
-                                finish(i)
-                            if rb is not None:
-                                rb.attrs["finished"] = int(done)
-                    drain_arrivals()
+                if chunked:
+                    pref = [i for i, s in enumerate(slots)
+                            if s is not None and s.prefilling]
+                    if pref:
+                        self._prefill(run, min(pref, key=lambda j: (
+                            slots[j].req.arrival, slots[j].req.uid)))
 
                 active = [i for i, s in enumerate(slots)
                           if s is not None and not s.prefilling]
@@ -746,17 +655,48 @@ class ContinuousScheduler(_SchedulerBase):
                         continue        # prefill chunks still in flight
                     if pending:
                         # idle: jump the virtual clock to the next arrival
-                        clock = max(clock, pending[0].arrival)
+                        run.clock = max(run.clock, pending[0].arrival)
                         continue
                     if queue:
                         continue        # admission will retry (or raise)
                     break
 
-                # one decode step over the fully-prefilled slots
-                self._decode_and_read(slots, active, temperature, base_key,
-                                      finish, dec_h, occ_h)
-                clock += 1.0
-        return results
+                # one decode step over every slot (inactive slots write the
+                # scratch block and their logits are discarded)
+                self._decode_and_read(run, active)
+                run.clock += 1.0
+        return run.results
+
+
+class _Run:
+    """The state of one ``ContinuousScheduler.run``: requests not yet due,
+    arrived ones waiting for a slot, the slots, the finished outputs, the
+    virtual clock and the registry's handles."""
+
+    def __init__(self, sched: ContinuousScheduler, requests: List[Request],
+                 temperature: float):
+        self.pending = deque(sorted(requests, key=lambda r: (r.arrival,
+                                                             r.uid)))
+        self.queue: deque = deque()     # arrived, waiting for a slot
+        self.arrive_wall: Dict[int, float] = {}
+        self.slots: List[Optional[_Slot]] = [None] * sched.batch
+        self.results: Dict[int, List[int]] = {}
+        self.clock = 0.0                # virtual steps
+        self.temperature = temperature
+        self.base_key = jax.random.PRNGKey(sched.seed)
+        (self.ttft_h, self.dec_h, self.occ_h, self.req_c,
+         self.tok_c) = sched._metric_handles()
+        if sched.chunk_tokens is not None:
+            self.hit_c = sched.metrics.counter("serve.prefix_hit_tokens")
+            self.miss_c = sched.metrics.counter("serve.prefix_miss_tokens")
+
+    def drain_arrivals(self) -> None:
+        """Queue the requests the virtual clock has reached."""
+        now = time.perf_counter()
+        while self.pending and self.pending[0].arrival <= self.clock:
+            r = self.pending.popleft()
+            self.queue.append(r)
+            self.arrive_wall[r.uid] = now
 
 
 class CohortScheduler(_SchedulerBase):
